@@ -21,8 +21,8 @@ compaction, post-PR) followed by ``WINDOW`` grouped consume steps.
 * pre-PR:  lexsort encode, then per-step XLA gathers of both operands
   (``grouped_matmul``) — W re-gathered every step;
 * fused:   tiled-kernel encode + ``compact_weights`` once, then per-step
-  ``grouped_matmul_fused`` reading the cached ``(G, cap)`` compact weights
-  straight from the encode output (the OSEL→core handoff).
+  ``grouped_matmul`` fed the cached ``(G, cap)`` compact weights straight
+  from the encode output (the OSEL→core handoff; no per-step W gather).
 
 ``kernel_beats_lexsort_above_4096`` asserts the fused window wins at every
 M > 4096 cell. On a CPU host both kernels run in interpret mode (the
@@ -97,9 +97,9 @@ def _sweep_cell(m, reps=5):
     gather = jax.jit(lambda x, w: fops.grouped_matmul(
         x, w, plan.row_ids, plan.col_ids, plan.row_valid, plan.col_valid,
         interpret=True))
-    fused = jax.jit(lambda x, wc: fops.grouped_matmul_fused(
-        x, wc, plan.row_ids, plan.row_valid, plan.col_ids, plan.col_valid,
-        n=n, interpret=True))
+    fused = jax.jit(lambda x, wc: fops.grouped_matmul(
+        x, w, plan.row_ids, plan.col_ids, plan.row_valid, plan.col_valid,
+        wc, interpret=True))
     consume = timeit_interleaved(
         {"gather": lambda: gather(x, w), "fused": lambda: fused(x, wc)},
         reps=reps, stat="median")
@@ -125,9 +125,9 @@ def _oversize_bitwise(m=4352, g=SWEEP_G, b=SWEEP_B, n=512):
                  zip(jax.tree.leaves(plan), jax.tree.leaves(ref)))
     wc = fops.compact_weights(w, plan.row_ids, plan.col_ids,
                               plan.row_valid, plan.col_valid)
-    y_fused = fops.grouped_matmul_fused(
-        x, wc, plan.row_ids, plan.row_valid, plan.col_ids, plan.col_valid,
-        n=n, interpret=True)
+    y_fused = fops.grouped_matmul(
+        x, w, plan.row_ids, plan.col_ids, plan.row_valid, plan.col_valid,
+        wc, interpret=True)
     y_gather = fops.grouped_matmul(
         x, w, plan.row_ids, plan.col_ids, plan.row_valid, plan.col_valid,
         interpret=True)

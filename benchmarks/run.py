@@ -11,6 +11,8 @@ import traceback
 
 
 def main(argv=None) -> int:
+    from repro import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="skip the MARL accuracy sweep (slowest)")

@@ -28,6 +28,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.marl import async_train as async_mod
 from repro.marl import envs as envs_mod
 from repro.marl import ic3net
@@ -35,6 +36,7 @@ from repro.marl import train as train_mod
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--env", default="predator_prey",
                     choices=envs_mod.names())

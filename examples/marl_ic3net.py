@@ -19,6 +19,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import flgw
 from repro.core.schedule import SparsitySchedule
 from repro.marl import envs as envs_mod
@@ -27,6 +28,7 @@ from repro.marl import train as train_mod
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--env", default="predator_prey",
                     choices=envs_mod.names())

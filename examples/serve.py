@@ -26,6 +26,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.core import encoder
 from repro.models import transformer
@@ -35,6 +36,7 @@ from repro.serving.stream import max_seq_for
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2_2b")
     ap.add_argument("--batch", type=int, default=4,

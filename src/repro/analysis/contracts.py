@@ -141,7 +141,8 @@ _COMPILE_LOGGERS = (
     "jax._src.dispatch",
     "jax._src.pjit",
 )
-_COMPILE_RE = re.compile(r"^Compiling ([^\s]+)")
+# jax 0.9 logs the name wrapped as ``jit(<name>)``; the bare name is kept.
+_COMPILE_RE = re.compile(r"^Compiling (?:jit\(([^\s()]+)\)|([^\s]+))")
 
 # Eager jnp/lax/random ops executed outside any user jit compile under
 # the *library function's* name — sometimes the public one ("less",
@@ -209,7 +210,8 @@ class _CompileHandler(logging.Handler):
             return
         m = _COMPILE_RE.match(msg)
         if m:
-            self.monitor.events.append(CompileEvent(m.group(1), msg))
+            self.monitor.events.append(
+                CompileEvent(m.group(1) or m.group(2), msg))
 
 
 @contextlib.contextmanager

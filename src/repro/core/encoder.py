@@ -142,7 +142,7 @@ def attach_compact(state: PlanState, params: dict) -> PlanState:
     """Attach compact weights (``GroupPlan.wc``) to every plan in a state.
 
     The serving-side half of the OSEL handoff: gather once per params
-    version, consume through the fused kernel until the params move. The
+    version, consume it in every grouped kernel call until the params move. The
     signature is layout-only — it does *not* certify ``wc`` — so holders
     of an attached state must re-attach at every params boundary (the
     refresh hooks below do this automatically) and must never share the

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import default_interpret
 from repro.kernels.flgw_matmul import ops as kops
 from repro.kernels.plan_encode import ops as pe_ops
 from repro.sharding.partition import constrain
@@ -206,9 +207,8 @@ def attach_compact(plans: RawPlans, params: dict) -> RawPlans:
     sparse *data*, not just indices, and the cores consume it directly).
 
     One XLA gather per projection, amortized over every consume until the
-    params move; :func:`grouped_apply` then takes the fused kernel path
-    (``flgw_matmul.grouped_matmul_fused``), which reads ``wc`` as-is and
-    gathers only the activations — in its prologue. ``wc`` snapshots
+    params move; :func:`grouped_apply` then feeds ``wc`` to the kernel
+    as-is and gathers only the activations. ``wc`` snapshots
     weight *values*: re-attach whenever params change (the plan signature
     does **not** cover it — see :class:`GroupPlan`). Stacked/scanned and
     vmapped-expert layers attach along their leading dims unchanged.
@@ -259,18 +259,13 @@ def _gather_w(w, plan: GroupPlan):
 
 
 def _core_matmul(x, w, plan: GroupPlan, interpret, impl):
-    """One compact product. Plans carrying attached compact weights take
-    the fused OSEL→core path (in-kernel activation gather, zero per-call
-    W traffic); bare plans take the per-call XLA-gather path; the jnp
-    reference stays the GSPMD-shardable fallback. The three agree —
-    fused vs gather bitwise (same tiles, same accumulation order)."""
-    if plan.wc is not None and impl != "reference":
-        return kops.grouped_matmul_fused(x, plan.wc, plan.row_ids,
-                                         plan.row_valid, plan.col_ids,
-                                         plan.col_valid, n=w.shape[1],
-                                         interpret=interpret)
+    """One compact product. Plans carrying attached compact weights skip
+    the per-call W gather (``plan.wc`` feeds the kernel as-is); bare plans
+    gather W per call; the jnp reference stays the GSPMD-shardable
+    fallback. Cached and gathered ``W_c`` are the same values, so the two
+    kernel paths agree bitwise."""
     return kops.grouped_matmul(x, w, plan.row_ids, plan.col_ids,
-                               plan.row_valid, plan.col_valid,
+                               plan.row_valid, plan.col_valid, plan.wc,
                                interpret=interpret, impl=impl)
 
 
@@ -369,7 +364,7 @@ def grouped_apply(x: jax.Array, w: jax.Array, ig: jax.Array, og: jax.Array,
     (see :func:`encode_plans`); when omitted the plan is re-derived here —
     the unamortized fallback, one encode per projection call.
     """
-    interpret = kops.default_interpret()
+    interpret = default_interpret()
     impl = "reference" if kops._REF_MODE else "pallas"
     if transpose:
         # y = x @ (W ⊙ M)^T == grouped(x, W^T) with IG/OG roles swapped.

@@ -33,13 +33,14 @@ def reference_impl_active() -> bool:
 
 
 def tpu_compiler_params(**kwargs):
-    """Mosaic compiler params across JAX versions.
-
-    jax >= 0.5 exposes ``pltpu.CompilerParams``; 0.4.x calls the same class
-    ``TPUCompilerParams``. All kernels route through this helper so they run
-    on either.
-    """
+    """Mosaic compiler params (``pltpu.CompilerParams``) for a kernel."""
     from jax.experimental.pallas import tpu as _pltpu
-    cls = getattr(_pltpu, "CompilerParams", None) \
-        or getattr(_pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    return _pltpu.CompilerParams(**kwargs)
+
+
+def default_interpret() -> bool:
+    """Whether a Pallas kernel runs in interpret mode when its caller does
+    not say: on every backend but the TPU (the CPU test suite), since
+    Mosaic compiles for the TPU only."""
+    import jax
+    return jax.default_backend() != "tpu"

@@ -55,8 +55,8 @@ def _fwd_case(p: dict) -> GridCase:
                     dt),
             Operand("out", (b, hq, s, d), (1, 1, bq, d),
                     lambda bi, h, i, j: (bi, h, i, 0), dt, role="out"),
-            Operand("lse", (b, hq, s), (1, 1, bq),
-                    lambda bi, h, i, j: (bi, h, i), F32, role="out"),
+            Operand("lse", (b, hq, s, 1), (1, 1, bq, 1),
+                    lambda bi, h, i, j: (bi, h, i, 0), F32, role="out"),
         ),
         accum_axes=frozenset({3}),
         scratch_bytes=(bq * d + bq + bq) * F32,
@@ -83,10 +83,10 @@ def _dq_case(p: dict) -> GridCase:
                     dt),
             Operand("do", (b, hq, s, d), (1, 1, bq, d),
                     lambda bi, h, i, j: (bi, h, i, 0), dt),
-            Operand("lse", (b, hq, s), (1, 1, bq),
-                    lambda bi, h, i, j: (bi, h, i), F32),
-            Operand("delta", (b, hq, s), (1, 1, bq),
-                    lambda bi, h, i, j: (bi, h, i), F32),
+            Operand("lse", (b, hq, s, 1), (1, 1, bq, 1),
+                    lambda bi, h, i, j: (bi, h, i, 0), F32),
+            Operand("delta", (b, hq, s, 1), (1, 1, bq, 1),
+                    lambda bi, h, i, j: (bi, h, i, 0), F32),
             Operand("dq", (b, hq, s, d), (1, 1, bq, d),
                     lambda bi, h, i, j: (bi, h, i, 0), dt, role="out"),
         ),
@@ -115,12 +115,12 @@ def _dkv_case(p: dict) -> GridCase:
             Operand("do", (b, hq, s, d), (1, 1, bq, d),
                     lambda bi, g, j, hg, i, qpk=qpk:
                     (bi, g * qpk + hg, i, 0), dt),
-            Operand("lse", (b, hq, s), (1, 1, bq),
+            Operand("lse", (b, hq, s, 1), (1, 1, bq, 1),
                     lambda bi, g, j, hg, i, qpk=qpk:
-                    (bi, g * qpk + hg, i), F32),
-            Operand("delta", (b, hq, s), (1, 1, bq),
+                    (bi, g * qpk + hg, i, 0), F32),
+            Operand("delta", (b, hq, s, 1), (1, 1, bq, 1),
                     lambda bi, g, j, hg, i, qpk=qpk:
-                    (bi, g * qpk + hg, i), F32),
+                    (bi, g * qpk + hg, i, 0), F32),
             Operand("dk", (b, hkv, t, d), (1, 1, bk, d),
                     lambda bi, g, j, hg, i: (bi, g, j, 0), dt,
                     role="out"),

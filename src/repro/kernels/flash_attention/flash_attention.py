@@ -13,7 +13,8 @@ FlashAttention, extended with the features our architectures need:
   * gemma-style attention-logit softcap (tanh), handled exactly in bwd;
   * f32 accumulation, bf16/f32 operands.
 
-Layouts: q (B, Hq, S, D), k/v (B, Hkv, T, D), out (B, Hq, S, D).
+Layouts: q (B, Hq, S, D), k/v (B, Hkv, T, D), out (B, Hq, S, D); the
+per-row statistics lse/delta are (B, Hq, S, 1).
 Backward is the standard two-pass scheme: a dq pass (grid over q blocks,
 stream k) and a dkv pass (grid over k blocks, stream q), both recomputing
 p from the saved logsumexp — nothing quadratic is ever stored.
@@ -99,10 +100,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(ik == nk - 1)
     def _flush():
-        l = l_ref[:, 0]
+        l = l_ref[...]                                 # (bq, 1)
         l_safe = jnp.where(l == 0, 1.0, l)             # fully-masked rows
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0, NEG_INF, m_ref[:, 0] + jnp.log(l_safe))
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.where(l == 0, NEG_INF, m_ref[...] + jnp.log(l_safe))
 
 
 @functools.partial(
@@ -110,7 +111,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                               "bq", "bk", "interpret"))
 def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
               bq=512, bk=512, interpret=False):
-    """Returns (out, lse). Shapes: q (B,Hq,S,D), k/v (B,Hkv,T,D)."""
+    """Returns (out, lse). Shapes: q (B,Hq,S,D), k/v (B,Hkv,T,D), lse
+    (B,Hq,S,1) — the trailing unit dim keeps the row statistics' block
+    ``(bq, 1)``, which Mosaic's (8, 128) tiling rule accepts."""
     b, hq, s, d = q.shape
     hkv, t = k.shape[1], k.shape[2]
     qpk = hq // hkv
@@ -134,11 +137,11 @@ def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -160,16 +163,17 @@ def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
 
 def _recompute_p_dz(q, k, lse_blk, do, v, delta_blk, *, scale, softcap,
                     mask):
-    """Shared bwd math for one (bq, bk) tile. Returns (p, dz)."""
+    """Shared bwd math for one (bq, bk) tile; ``lse_blk``/``delta_blk``
+    are (bq, 1) columns. Returns (p, dz)."""
     z_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
     z = _apply_softcap(z_raw, softcap)
     z = jnp.where(mask, z, NEG_INF)
-    p = jnp.exp(z - lse_blk[:, None])                   # (bq, bk)
+    p = jnp.exp(z - lse_blk)                            # (bq, bk)
     p = jnp.where(mask, p, 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    dz = p * (dp - delta_blk[:, None])                  # d logits (post-cap)
+    dz = p * (dp - delta_blk)                           # d logits (post-cap)
     if softcap > 0:
         dz = dz * (1.0 - jnp.square(jnp.tanh(z_raw / softcap)))
     return p, dz
@@ -260,7 +264,7 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
     nq, nk = s // bq, t // bk
 
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                            # (B, Hq, S)
+                    axis=-1, keepdims=True)             # (B, Hq, S, 1)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -273,8 +277,8 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
             pl.BlockSpec((1, 1, bk, d),
                          lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
@@ -298,12 +302,12 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
             pl.BlockSpec((1, 1, bq, d),
                          lambda b, g, j, hg, i, qpk=qpk:
                          (b, g * qpk + hg, i, 0)),
-            pl.BlockSpec((1, 1, bq),
+            pl.BlockSpec((1, 1, bq, 1),
                          lambda b, g, j, hg, i, qpk=qpk:
-                         (b, g * qpk + hg, i)),
-            pl.BlockSpec((1, 1, bq),
+                         (b, g * qpk + hg, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1),
                          lambda b, g, j, hg, i, qpk=qpk:
-                         (b, g * qpk + hg, i)),
+                         (b, g * qpk + hg, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b, g, j, hg, i: (b, g, j, 0)),

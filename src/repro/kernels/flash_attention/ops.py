@@ -9,8 +9,8 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.flash_attention.flash_attention import (flash_bwd,
                                                            flash_fwd)
 from repro.kernels.flash_attention import ref as _ref
@@ -18,10 +18,6 @@ from repro.kernels.flash_attention import ref as _ref
 # (repro.kernels.flash_attention.audit) so the audited grid is, by
 # construction, the grid this wrapper builds.
 from repro.kernels.tiling import pick_block as _pick_block
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp,

@@ -2,9 +2,8 @@
 # so the jax-free audit module (audit.py / repro.analysis.kernel_audit)
 # can load its KernelSpecs in the no-jax CI analysis job.
 _EXPORTS = {
-    "compact_weights": "ops", "grouped_matmul": "ops",
-    "grouped_matmul_fused": "ops", "reference": "ops",
-    "fused_bmm": "flgw_matmul", "grouped_bmm": "flgw_matmul",
+    "compact_weights": "ops", "grouped_matmul": "ops", "reference": "ops",
+    "grouped_bmm": "flgw_matmul",
 }
 __all__ = list(_EXPORTS)
 

@@ -1,11 +1,11 @@
-"""KernelSpecs for the FLGW grouped-matmul kernels (jax-free).
+"""KernelSpec for the FLGW grouped-matmul kernel (jax-free).
 
 Mirrors the exact grid/BlockSpec construction of
-``flgw_matmul.grouped_bmm`` and ``flgw_matmul.fused_bmm`` as driven by
-the ``ops.py`` wrappers (same :mod:`repro.kernels.tiling` helpers, same
-padding), so :mod:`repro.analysis.kernel_audit` can prove bounds /
-coverage / write-disjointness / VMEM for a whole shape corpus without
-compiling anything. The contracted ``k`` axis (grid axis 3) is the
+``flgw_matmul.grouped_bmm`` as driven by the ``ops.py`` wrapper (same
+:mod:`repro.kernels.tiling` helpers, same padding), so
+:mod:`repro.analysis.kernel_audit` can prove bounds / coverage /
+write-disjointness / VMEM for a whole shape corpus without compiling
+anything. The contracted ``k`` axis (grid axis 3) is the
 declared accumulation axis: every output tile is legitimately revisited
 once per k-step into the f32 VMEM scratch accumulator.
 
@@ -74,32 +74,6 @@ def _grouped_bmm_case(p: dict) -> GridCase:
     )
 
 
-def _fused_bmm_case(p: dict) -> GridCase:
-    g, cap_m, cap_n = _caps(p)
-    dt = p.get("itemsize", F32)
-    bb, bn, bk, bp, mp, np_ = _tiles(p["b"], cap_m, cap_n)
-    m1 = p["m"] + 1                       # appended zero column
-    grid = (g, bp // bb, np_ // bn, mp // bk)
-    return GridCase(
-        label=_label(p), grid=grid,
-        operands=(
-            # the whole contracted width rides VMEM so the in-kernel
-            # activation gather stays local — the VMEM-dominant block
-            Operand("xp", (bp, m1), (bb, m1),
-                    lambda gi, i, j, k: (i, 0), dt),
-            Operand("wc", (g, mp, np_), (1, bk, bn),
-                    lambda gi, i, j, k: (gi, k, j), dt),
-            Operand("ids", (g, mp), (1, bk),
-                    lambda gi, i, j, k: (gi, k), 4),
-            Operand("yc", (g, bp, np_), (1, bb, bn),
-                    lambda gi, i, j, k: (gi, i, j), dt, role="out"),
-        ),
-        accum_axes=frozenset({3}),
-        scratch_bytes=bb * bn * F32,
-        tags=_tags(p),
-    )
-
-
 register_kernel_spec(KernelSpec(
     name="flgw_matmul.grouped_bmm",
     module="repro.kernels.flgw_matmul.flgw_matmul",
@@ -109,18 +83,8 @@ register_kernel_spec(KernelSpec(
         {"b": 128, "m": 1024, "n": 1024, "g": 8},     # training tile
         {"b": 64, "m": 512, "n": 512, "g": 4, "slack": 1.5},
         {"b": 32, "m": 8192, "n": 8192, "g": 16},     # d_ff scale
-    ),
-    note="XLA-gather grouped path; k accumulates in VMEM scratch",
-))
-
-register_kernel_spec(KernelSpec(
-    name="flgw_matmul.fused_bmm",
-    module="repro.kernels.flgw_matmul.flgw_matmul",
-    build=_fused_bmm_case,
-    corpus=(
-        {"b": 2, "m": 8192, "n": 8192, "g": 4},       # fig13 d_ff decode
-        {"b": 128, "m": 256, "n": 256, "g": 4, "slack": 1.5},
+        {"b": 2, "m": 8192, "n": 8192, "g": 4},       # d_ff decode
         {"b": 8, "m": 4352, "n": 512, "g": 8, "slack": 1.25},
     ),
-    note="OSEL-to-core fused path; (bb, M+1) activation block dominates",
+    note="XLA-gather grouped path; k accumulates in VMEM scratch",
 ))

@@ -14,14 +14,6 @@ HBM→VMEM via BlockSpec. Compute drops by exactly G versus the dense layer.
 
 Grid: (G, B/bb, capN/bn, capM/bk) with accumulation over the bk axis in an
 f32 VMEM scratch accumulator.
-
-``fused_bmm`` is the OSEL→core handoff variant: it consumes the ``(G, cap)``
-compact format straight from the plan-encode output — the activation gather
-``x -> x_c`` happens in the kernel prologue (a per-tile ``jnp.take`` against
-the row-id tile) instead of as XLA VPU scatter/gather work, and the weight
-side arrives already compacted (``W_c`` from the encode stage's
-``compact_weights``). Invalid slots are routed to a zero column appended to
-``x``, so the gather itself performs the masking.
 """
 from __future__ import annotations
 
@@ -88,68 +80,3 @@ def grouped_bmm(xg: jax.Array, wc: jax.Array, *, bb: int = 128,
         ),
         interpret=interpret,
     )(xg, wc)
-
-
-def _fused_kernel(x_ref, wc_ref, ids_ref, out_ref, acc_ref, *, k_steps: int):
-    """One (g, b-tile, n-tile, k-tile) grid step with the x-gather fused
-    into the prologue: the (bb, bk) compact activation tile is gathered
-    from the full-width x block by this k-tile's row ids. Invalid slots
-    hold ``m`` — the appended zero column — so the gather masks for free
-    and the accumulated products match the XLA-gather path bitwise."""
-
-    @pl.when(pl.program_id(3) == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ids = ids_ref[0]                                     # (bk,) int32
-    xt = jnp.take(x_ref[...], ids, axis=1)               # (bb, bk)
-    acc_ref[...] += jax.lax.dot_general(
-        xt, wc_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(pl.program_id(3) == k_steps - 1)
-    def _flush():
-        out_ref[0, ...] = acc_ref[...].astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bb", "bn", "bk", "interpret"))
-def fused_bmm(x: jax.Array, wc: jax.Array, row_ids: jax.Array, *,
-              bb: int = 128, bn: int = 128, bk: int = 128,
-              interpret: bool = False) -> jax.Array:
-    """(B, M+1) x, (G, capM, capN) wc, (G, capM) row ids -> (G, B, capN).
-
-    ``x``'s last column must be zero (the invalid-slot sink: every padding
-    or invalid ``row_ids`` entry must equal ``M``). ``B``/``capM``/``capN``
-    must be multiples of the tile sizes (ops.py pads). The per-step VMEM
-    working set is dominated by the (bb, M+1) activation block — the
-    whole contracted width rides VMEM so the per-tile gather stays
-    local; it is audited statically over a shape corpus, including the
-    M > 4096 decode cases — see ``audit.py`` beside this module and
-    ``python -m repro.analysis.kernel_audit`` for the numbers.
-    """
-    b, m1 = x.shape
-    g, cap_m, n = wc.shape
-    assert row_ids.shape == (g, cap_m), (row_ids.shape, wc.shape)
-    assert b % bb == 0 and n % bn == 0 and cap_m % bk == 0, (x.shape,
-                                                            wc.shape)
-    k_steps = cap_m // bk
-
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, k_steps=k_steps),
-        grid=(g, b // bb, n // bn, k_steps),
-        in_specs=[
-            pl.BlockSpec((bb, m1), lambda g, i, j, k: (i, 0)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-            pl.BlockSpec((1, bk), lambda g, i, j, k: (g, k)),
-        ],
-        out_specs=pl.BlockSpec((1, bb, bn), lambda g, i, j, k: (g, i, j)),
-        out_shape=jax.ShapeDtypeStruct((g, b, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-    )(x, wc, row_ids)

@@ -10,13 +10,10 @@ Pipeline (the TPU analogue of LearningGroup's load-allocation unit + cores):
 The gathers/scatter are memory-bound VPU work handled by XLA; the matmul is
 the Pallas kernel. On non-TPU backends the kernel runs in interpret mode.
 
-:func:`grouped_matmul_fused` is the OSEL→core variant: step 2's compact
-weights come straight from the encode stage (:func:`compact_weights`,
-cached beside the plan for the life of a params version) and step 1's
-activation gather moves into the kernel prologue — the per-call XLA
-gathers disappear from the hot path. :func:`grouped_matmul` (per-call XLA
-gathers) remains the no-cached-weights default and, with
-``impl="reference"``, the GSPMD-shardable fallback.
+Step 2 is skipped when the caller passes ``wc``: the compact weights from
+:func:`compact_weights`, cached beside the plan for the life of a params
+version (the paper's OSEL→core handoff) — the serving consume path.
+``impl="reference"`` lowers the jnp reference instead (GSPMD-shardable).
 """
 from __future__ import annotations
 
@@ -25,7 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flgw_matmul.flgw_matmul import fused_bmm, grouped_bmm
+from repro.kernels import default_interpret
+from repro.kernels.flgw_matmul.flgw_matmul import grouped_bmm
 from repro.kernels.flgw_matmul import ref as _ref
 
 # Reference-impl mode: under plain jit, GSPMD cannot partition a pallas
@@ -43,21 +41,20 @@ from repro.kernels.tiling import pick_tile as _pick_tile
 from repro.kernels.tiling import round_up as _round_up
 
 
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "impl"))
 def grouped_matmul(x: jax.Array, w: jax.Array, row_ids: jax.Array,
                    col_ids: jax.Array, row_valid: jax.Array,
-                   col_valid: jax.Array, *,
+                   col_valid: jax.Array, wc: jax.Array | None = None, *,
                    interpret: bool | None = None,
                    impl: str = "pallas") -> jax.Array:
     """Compact FLGW matmul. Shapes: x (B, M), w (M, N), row_ids (G, capM),
     col_ids (G, capN); returns y (B, N). See ref.ref_grouped_matmul.
 
-    ``impl="reference"`` lowers the jnp reference instead of the Pallas
-    kernel (GSPMD-shardable; see use_reference_impl)."""
+    ``wc``: the cached ``(G, capM, capN)`` compact weights
+    (:func:`compact_weights` of this ``w`` and plan); when given, the
+    per-call weight gather is skipped. ``impl="reference"`` lowers the jnp
+    reference instead of the Pallas kernel (GSPMD-shardable; see
+    use_reference_impl) and reads ``w`` only."""
     if impl == "reference" or _REF_MODE:
         return _ref.ref_grouped_matmul(x, w, row_ids, col_ids, row_valid,
                                        col_valid)
@@ -72,8 +69,10 @@ def grouped_matmul(x: jax.Array, w: jax.Array, row_ids: jax.Array,
     xg = jnp.take(x, row_ids.reshape(-1), axis=1)
     xg = xg.reshape(b, g, cap_m).transpose(1, 0, 2)          # (G, B, capM)
     xg = jnp.where(row_valid[:, None, :], xg, 0)
-    wc = w[row_ids[:, :, None], col_ids[:, None, :]]         # (G, capM, capN)
-    wc = jnp.where(row_valid[:, :, None] & col_valid[:, None, :], wc, 0)
+    if wc is None:
+        wc = compact_weights(w, row_ids, col_ids, row_valid, col_valid)
+    assert wc.shape == (g, cap_m, cap_n), (wc.shape, row_ids.shape,
+                                           col_ids.shape)
 
     # --- pad to tile multiples for the kernel ------------------------------
     bb = _pick_tile(b, 128)
@@ -98,63 +97,15 @@ def compact_weights(w: jax.Array, row_ids: jax.Array, col_ids: jax.Array,
 
     This is the paper's OSEL handoff — the dense weight compacted into the
     ``(G, cap)`` format the cores consume directly. Invalid slots are
-    zeroed, which is also what makes the fused path bitwise-equal to the
-    XLA-gather path: a zero W_c row annihilates whatever the activation
-    gather produced for that slot. Handles stacked leading dims (scanned
-    decoder layers, vmapped experts) by folding them into a vmap.
+    zeroed, so a cached ``W_c`` is exactly what :func:`grouped_matmul`
+    would gather per call. Handles stacked leading dims (scanned decoder
+    layers, vmapped experts) by folding them into a vmap.
     """
     if w.ndim > 2:
         return jax.vmap(compact_weights)(w, row_ids, col_ids, row_valid,
                                          col_valid)
     wc = w[row_ids[:, :, None], col_ids[:, None, :]]         # (G, capM, capN)
     return jnp.where(row_valid[:, :, None] & col_valid[:, None, :], wc, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def grouped_matmul_fused(x: jax.Array, wc: jax.Array, row_ids: jax.Array,
-                         row_valid: jax.Array, col_ids: jax.Array,
-                         col_valid: jax.Array, *, n: int,
-                         interpret: bool | None = None) -> jax.Array:
-    """Compact FLGW matmul consuming the encode output directly.
-
-    Instead of re-gathering both operands through XLA per call
-    (:func:`grouped_matmul`), this takes ``wc`` — the ``(G, capM, capN)``
-    compact weights from :func:`compact_weights`, typically cached beside
-    the plan for the whole life of a params version — and fuses the
-    activation gather ``x -> x_c`` into the kernel prologue
-    (:func:`~repro.kernels.flgw_matmul.flgw_matmul.fused_bmm`): invalid
-    row slots are pointed at a zero column appended to ``x``, so a single
-    in-kernel gather replaces XLA's gather + mask + transpose chain.
-    Bitwise-identical to :func:`grouped_matmul` (same tile sizes, same
-    accumulation order, and zero-masked ``wc`` rows annihilate whatever
-    the gather pulls for invalid slots). ``n`` is the dense output width.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    b, m = x.shape
-    g, cap_m = row_ids.shape
-    cap_n = col_ids.shape[1]
-    assert wc.shape == (g, cap_m, cap_n), (wc.shape, row_ids.shape,
-                                           col_ids.shape)
-
-    # Invalid/padding slots gather the appended zero column (index m).
-    ids = jnp.where(row_valid, row_ids, m)
-    xp = jnp.pad(x, ((0, 0), (0, 1)))                        # (B, M+1)
-
-    bb = _pick_tile(b, 128)
-    bn = _pick_tile(cap_n, 128)
-    bk = _pick_tile(cap_m, 128)
-    bp, mp, np_ = _round_up(b, bb), _round_up(cap_m, bk), _round_up(cap_n, bn)
-    xp = jnp.pad(xp, ((0, bp - b), (0, 0)))
-    ids = jnp.pad(ids, ((0, 0), (0, mp - cap_m)), constant_values=m)
-    wc = jnp.pad(wc, ((0, 0), (0, mp - cap_m), (0, np_ - cap_n)))
-
-    yc = fused_bmm(xp, wc, ids, bb=bb, bn=bn, bk=bk, interpret=interpret)
-    yc = yc[:, :b, :cap_n]                                   # (G, B, capN)
-
-    flat_cols = jnp.where(col_valid, col_ids, n).reshape(-1)
-    yt = yc.transpose(1, 0, 2).reshape(b, -1)
-    return jnp.zeros((b, n), x.dtype).at[:, flat_cols].set(yt, mode="drop")
 
 
 def reference(x, w, row_ids, col_ids, row_valid, col_valid):

@@ -2,14 +2,10 @@
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels import default_interpret
 from repro.kernels.osel_encode.osel_encode import encode_mask
 from repro.kernels.osel_encode import ref as _ref
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def osel_mask(ig_idx: jax.Array, og_idx: jax.Array,

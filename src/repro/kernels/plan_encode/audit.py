@@ -57,8 +57,8 @@ def _rank_case(p: dict) -> GridCase:
                     lambda i, ti, tj: (i, 0, tj), F32),
             Operand("rank", (l, mp, 1), (1, b, 1),
                     lambda i, ti, tj: (i, ti, 0), I32, role="out"),
-            Operand("hist", (l, n_t, g), (1, 1, g),
-                    lambda i, ti, tj: (i, ti, 0), I32, role="out"),
+            Operand("hist", (l, n_t, 1, g), (1, 1, 1, g),
+                    lambda i, ti, tj: (i, ti, 0, 0), I32, role="out"),
         ),
         accum_axes=frozenset({2}),
         scratch_bytes=b * 1 * I32,

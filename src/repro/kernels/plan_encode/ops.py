@@ -22,7 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import reference_impl_active
+from repro.kernels import default_interpret, reference_impl_active
 from repro.kernels.plan_encode import ref as _ref
 from repro.kernels.plan_encode.plan_encode import assign_slots
 # Placement-tile selection is shared with the static auditor
@@ -55,10 +55,6 @@ def resolve_impl(items: int, impl: str | None = None) -> str:
     if reference_impl_active():
         return "reference"
     return "pallas"
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("axis", "slack", "interpret",

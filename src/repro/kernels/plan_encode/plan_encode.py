@@ -75,7 +75,7 @@ def _rank_kernel(pref_c_ref, str_c_ref, pref_r_ref, str_r_ref, rank_ref,
         # group ``g`` and drop out of the (bi, G) one-hot.
         gi_row = jax.lax.broadcasted_iota(jnp.int32, (bi, g), 1)
         onehot = (pref_c == gi_row).astype(jnp.int32)
-        hist_ref[0] = jnp.sum(onehot, axis=0, keepdims=True)   # (1, G)
+        hist_ref[0, 0] = jnp.sum(onehot, axis=0, keepdims=True)  # (1, G)
 
 
 def _place_kernel(pref_c_ref, rank_ref, hist_ref, slot_ref, *, g: int,
@@ -154,11 +154,14 @@ def assign_slots(pref_c: jax.Array, str_c: jax.Array, pref_r: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
-            pl.BlockSpec((1, 1, g), lambda i, ti, tj: (i, ti, 0)),
+            # (1, G) rows as the two full trailing dims: Mosaic tiles
+            # the last two block dims by (8, 128) unless they span the
+            # whole array dimension.
+            pl.BlockSpec((1, 1, 1, g), lambda i, ti, tj: (i, ti, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((l, mp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((l, n_it, g), jnp.int32),
+            jax.ShapeDtypeStruct((l, n_it, 1, g), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((bi, 1), jnp.int32)],
         compiler_params=tpu_compiler_params(
@@ -167,6 +170,7 @@ def assign_slots(pref_c: jax.Array, str_c: jax.Array, pref_r: jax.Array,
         interpret=interpret,
     )(pref_c, str_c, pref_r, str_r)
 
+    hist = hist.reshape(l, n_it, g)
     return pl.pallas_call(
         functools.partial(_place_kernel, g=g, cap=cap, bi=bi),
         grid=(l, n_it),

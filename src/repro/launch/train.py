@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.core.schedule import SparsitySchedule
 from repro.data.pipeline import SyntheticTokens, make_batch_iterator
@@ -109,6 +110,7 @@ def train_lm(arch: str, *, smoke: bool = True, steps: int = 20,
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     choices=[a for a in registry.ARCH_IDS if a != "ic3net"])

@@ -348,10 +348,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     grouped projections fall back to per-call re-encoding.
 
     ``compact``: also attach the compact weights (``GroupPlan.wc`` — the
-    weight half of the OSEL encode output) so decode steps consume the
-    fused kernel path with zero per-call W gathers. Defaults to on
+    weight half of the OSEL encode output) so decode steps feed the
+    grouped kernel with zero per-call W gathers. Defaults to on
     whenever ``params`` is given; pass ``False`` for a layout-only
-    PlanState (e.g. to measure the unfused path). The attached weights
+    PlanState (e.g. to measure the per-call-gather path). The attached weights
     snapshot this params version — re-attach at params boundaries
     (:func:`refresh_cache_plans` does, even when the layout signature
     certifies).

@@ -88,7 +88,7 @@ class ServeSession:
         by the layout signature, which never hashes weight values, so
         weight-bearing states must not live there (or in ``self.plans``,
         which concurrent sessions share by identity). The compact weights
-        (``GroupPlan.wc``, the fused consume path's operand) are attached
+        (``GroupPlan.wc``, the grouped kernel's cached weight operand) are attached
         session-locally at the consumption points (:meth:`new_cache`,
         :meth:`refresh`, :meth:`prefill`) via :meth:`_attach`."""
         if self.plan_policy == "off" or not self._grouped:

@@ -91,7 +91,7 @@ def test_grouped_matmul_balanced_plan_equals_masked_oracle():
 
 
 # ---------------------------------------------------------------------------
-# grouped_matmul_fused: cached W_c + in-kernel activation gather
+# cached compact weights: GroupPlan.wc fed to grouped_matmul as-is
 # ---------------------------------------------------------------------------
 
 def _fused_pair(m, n, g, b, slack, dtype, seed=None):
@@ -111,16 +111,16 @@ def _fused_pair(m, n, g, b, slack, dtype, seed=None):
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_bitwise_matches_gather_path(m, n, g, b, slack, dtype):
-    """The fused consume path (compact ``W_c`` + in-kernel activation
-    gather) is *bitwise* equal to the XLA-gather ``grouped_matmul`` —
-    same tile sizes, same accumulation order, identical gathered operands
-    — so callers can flip paths per call with no parity budget."""
+    """The serving consume path (compact ``W_c`` cached beside the plan
+    and fed to the kernel as-is) is *bitwise* equal to the per-call
+    weight gather — the kernel sees the same operands either way — so
+    callers can flip paths per call with no parity budget."""
     x, w, plan = _fused_pair(m, n, g, b, slack, dtype)
     wc = fops.compact_weights(w, plan.row_ids, plan.col_ids,
                               plan.row_valid, plan.col_valid)
-    got = fops.grouped_matmul_fused(x, wc, plan.row_ids, plan.row_valid,
-                                    plan.col_ids, plan.col_valid, n=n,
-                                    interpret=True)
+    got = fops.grouped_matmul(x, w, plan.row_ids, plan.col_ids,
+                              plan.row_valid, plan.col_valid, wc,
+                              interpret=True)
     want = fops.grouped_matmul(x, w, plan.row_ids, plan.col_ids,
                                plan.row_valid, plan.col_valid,
                                interpret=True)
@@ -129,8 +129,8 @@ def test_fused_bitwise_matches_gather_path(m, n, g, b, slack, dtype):
 
 
 def test_compact_weights_zeroes_invalid_slots():
-    """Invalid (padding) slots of W_c are zero — the property that makes
-    the fused path's sink-column gather annihilate padding rows."""
+    """Invalid (padding) slots of W_c are zero, exactly as the per-call
+    gather in ``grouped_matmul`` masks them."""
     _, w, plan = _fused_pair(80, 48, 4, 3, 1.5, jnp.float32, seed=9)
     wc = fops.compact_weights(w, plan.row_ids, plan.col_ids,
                               plan.row_valid, plan.col_valid)

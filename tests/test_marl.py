@@ -442,7 +442,12 @@ def test_mesh_axes_actually_partition_on_forced_devices():
         "    lowered = chunk.lower(params, opt, key, plans,\n"
         "        jnp.zeros((), jnp.int32), 5, cfg2, ecfg, tcfg, env, sched)\n"
         "txt = lowered.as_text()\n"
-        "assert 'devices=[' in txt, 'no partitioned sharding in the chunk'\n"
+        "import re\n"
+        "axes = set(re.findall(r'sdy\\.sharding_constraint[^\\n]*\\{\"(env|agent)\"\\}',"
+        " txt))\n"
+        "assert axes == {'env', 'agent'}, f'unpartitioned chunk: {axes}'\n"
+        "hlo = lowered.compile().as_text()\n"
+        "assert 'all-reduce' in hlo, 'the partitioned chunk reduces nothing'\n"
         "_, hist = T.train(cfg, ecfg, tcfg, iterations=5, seed=0,"
         " schedule=sched, log_every=5)\n"
         "assert len(hist) == 5\n"
