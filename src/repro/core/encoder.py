@@ -38,6 +38,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import grouped
 
 REFRESH_MODES = ("period", "on_change", "hybrid")
@@ -191,15 +192,16 @@ def maybe_refresh(params: dict, state: PlanState, it, cfg,
     attached = grouped.has_compact(state.plans)
     fresh = (lambda: attach_compact(encode_plans(params, cfg), params)) \
         if attached else (lambda: encode_plans(params, cfg))
-    if mode == "period" and k == 1:
-        return fresh()
-    due = jnp.asarray(it, jnp.int32) % k == 0
-    if mode == "period":
-        pred = due
-    else:
-        changed = plan_signature(params) != state.sig
-        pred = changed if mode == "on_change" else changed | due
-    return jax.lax.cond(pred, fresh, lambda: _certify(state, params))
+    with jax.named_scope(scopes.PLAN_REFRESH):
+        if mode == "period" and k == 1:
+            return fresh()
+        due = jnp.asarray(it, jnp.int32) % k == 0
+        if mode == "period":
+            pred = due
+        else:
+            changed = plan_signature(params) != state.sig
+            pred = changed if mode == "on_change" else changed | due
+        return jax.lax.cond(pred, fresh, lambda: _certify(state, params))
 
 
 def refresh_if_stale(params: dict, state: PlanState, cfg=None, *,

@@ -125,34 +125,36 @@ def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                window=window, softcap=softcap, nk=nk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b, hq, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(b, hq, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
+                jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+            ],
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            name="flash_fwd",
+            interpret=interpret,
+        )(q, k, v)
     return out, lse
 
 
@@ -266,64 +268,73 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)             # (B, Hq, S, 1)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          window=window, softcap=softcap, nk=nk),
-        grid=(b, hq, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    with jax.named_scope("flash_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, scale=scale, causal=causal,
+                              window=window, softcap=softcap, nk=nk),
+            grid=(b, hq, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, bq, d),
+                                   lambda b, h, i, j: (b, h, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            name="flash_dq",
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          window=window, softcap=softcap, nq=nq, qpk=qpk),
-        grid=(b, hkv, nk, qpk, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda b, g, j, hg, i, qpk=qpk:
-                         (b, g * qpk + hg, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, g, j, hg, i: (b, g, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, g, j, hg, i: (b, g, j, 0)),
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda b, g, j, hg, i, qpk=qpk:
-                         (b, g * qpk + hg, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1),
-                         lambda b, g, j, hg, i, qpk=qpk:
-                         (b, g * qpk + hg, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1),
-                         lambda b, g, j, hg, i, qpk=qpk:
-                         (b, g * qpk + hg, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda b, g, j, hg, i: (b, g, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, g, j, hg, i: (b, g, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, t, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    with jax.named_scope("flash_dkdv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                              window=window, softcap=softcap, nq=nq, qpk=qpk),
+            grid=(b, hkv, nk, qpk, nq),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d),
+                             lambda b, g, j, hg, i, qpk=qpk:
+                             (b, g * qpk + hg, i, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, g, j, hg, i: (b, g, j, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, g, j, hg, i: (b, g, j, 0)),
+                pl.BlockSpec((1, 1, bq, d),
+                             lambda b, g, j, hg, i, qpk=qpk:
+                             (b, g * qpk + hg, i, 0)),
+                pl.BlockSpec((1, 1, bq, 1),
+                             lambda b, g, j, hg, i, qpk=qpk:
+                             (b, g * qpk + hg, i, 0)),
+                pl.BlockSpec((1, 1, bq, 1),
+                             lambda b, g, j, hg, i, qpk=qpk:
+                             (b, g * qpk + hg, i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, g, j, hg, i: (b, g, j, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda b, g, j, hg, i: (b, g, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, hkv, t, d), k.dtype),
+                jax.ShapeDtypeStruct((b, hkv, t, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary", "arbitrary")),
+            name="flash_dkdv",
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -64,19 +64,21 @@ def grouped_bmm(xg: jax.Array, wc: jax.Array, *, bb: int = 128,
     assert b % bb == 0 and n % bn == 0 and m % bk == 0, (xg.shape, wc.shape)
     k_steps = m // bk
 
-    return pl.pallas_call(
-        functools.partial(_bmm_kernel, k_steps=k_steps),
-        grid=(g, b // bb, n // bn, k_steps),
-        in_specs=[
-            pl.BlockSpec((1, bb, bk), lambda g, i, j, k: (g, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bb, bn), lambda g, i, j, k: (g, i, j)),
-        out_shape=jax.ShapeDtypeStruct((g, b, n), xg.dtype),
-        scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-    )(xg, wc)
+    with jax.named_scope("grouped_bmm"):
+        return pl.pallas_call(
+            functools.partial(_bmm_kernel, k_steps=k_steps),
+            grid=(g, b // bb, n // bn, k_steps),
+            in_specs=[
+                pl.BlockSpec((1, bb, bk), lambda g, i, j, k: (g, i, k)),
+                pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g, k, j)),
+            ],
+            out_specs=pl.BlockSpec((1, bb, bn), lambda g, i, j, k: (g, i, j)),
+            out_shape=jax.ShapeDtypeStruct((g, b, n), xg.dtype),
+            scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
+            ),
+            name="grouped_bmm",
+            interpret=interpret,
+        )(xg, wc)

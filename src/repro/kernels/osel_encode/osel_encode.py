@@ -43,18 +43,20 @@ def encode_mask(ig_idx: jax.Array, og_idx: jax.Array, *, bm: int = 256,
                   constant_values=-1)[:, None]
     og2 = jnp.pad(og_idx.astype(jnp.int32), (0, np_ - n),
                   constant_values=-2)[None, :]
-    out = pl.pallas_call(
-        _encode_kernel,
-        grid=(mp // bm, np_ // bn),
-        in_specs=[
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.uint8),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel"),
-        ),
-        interpret=interpret,
-    )(ig2, og2)
+    with jax.named_scope("osel_encode"):
+        out = pl.pallas_call(
+            _encode_kernel,
+            grid=(mp // bm, np_ // bn),
+            in_specs=[
+                pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.uint8),
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "parallel"),
+            ),
+            name="osel_encode",
+            interpret=interpret,
+        )(ig2, og2)
     return out[:m, :n]

@@ -143,46 +143,50 @@ def assign_slots(pref_c: jax.Array, str_c: jax.Array, pref_r: jax.Array,
     n_it = mp // bi
     n_jt = mp // bj
 
-    rank, hist = pl.pallas_call(
-        functools.partial(_rank_kernel, g=g, bi=bi, bj=bj, n_jt=n_jt),
-        grid=(l, n_it, n_jt),
-        in_specs=[
-            pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
-            pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
-            pl.BlockSpec((1, 1, bj), lambda i, ti, tj: (i, 0, tj)),
-            pl.BlockSpec((1, 1, bj), lambda i, ti, tj: (i, 0, tj)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
-            # (1, G) rows as the two full trailing dims: Mosaic tiles
-            # the last two block dims by (8, 128) unless they span the
-            # whole array dimension.
-            pl.BlockSpec((1, 1, 1, g), lambda i, ti, tj: (i, ti, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((l, mp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((l, n_it, 1, g), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((bi, 1), jnp.int32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(pref_c, str_c, pref_r, str_r)
+    with jax.named_scope("plan_encode_rank"):
+        rank, hist = pl.pallas_call(
+            functools.partial(_rank_kernel, g=g, bi=bi, bj=bj, n_jt=n_jt),
+            grid=(l, n_it, n_jt),
+            in_specs=[
+                pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
+                pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
+                pl.BlockSpec((1, 1, bj), lambda i, ti, tj: (i, 0, tj)),
+                pl.BlockSpec((1, 1, bj), lambda i, ti, tj: (i, 0, tj)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bi, 1), lambda i, ti, tj: (i, ti, 0)),
+                # (1, G) rows as the two full trailing dims: Mosaic tiles
+                # the last two block dims by (8, 128) unless they span the
+                # whole array dimension.
+                pl.BlockSpec((1, 1, 1, g), lambda i, ti, tj: (i, ti, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((l, mp, 1), jnp.int32),
+                jax.ShapeDtypeStruct((l, n_it, 1, g), jnp.int32),
+            ],
+            scratch_shapes=[pltpu.VMEM((bi, 1), jnp.int32)],
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            ),
+            name="plan_encode_rank",
+            interpret=interpret,
+        )(pref_c, str_c, pref_r, str_r)
 
     hist = hist.reshape(l, n_it, g)
-    return pl.pallas_call(
-        functools.partial(_place_kernel, g=g, cap=cap, bi=bi),
-        grid=(l, n_it),
-        in_specs=[
-            pl.BlockSpec((1, bi, 1), lambda i, ti: (i, ti, 0)),
-            pl.BlockSpec((1, bi, 1), lambda i, ti: (i, ti, 0)),
-            pl.BlockSpec((1, n_it, g), lambda i, ti: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bi, 1), lambda i, ti: (i, ti, 0)),
-        out_shape=jax.ShapeDtypeStruct((l, mp, 1), jnp.int32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(pref_c, rank, hist)
+    with jax.named_scope("plan_encode_place"):
+        return pl.pallas_call(
+            functools.partial(_place_kernel, g=g, cap=cap, bi=bi),
+            grid=(l, n_it),
+            in_specs=[
+                pl.BlockSpec((1, bi, 1), lambda i, ti: (i, ti, 0)),
+                pl.BlockSpec((1, bi, 1), lambda i, ti: (i, ti, 0)),
+                pl.BlockSpec((1, n_it, g), lambda i, ti: (i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bi, 1), lambda i, ti: (i, ti, 0)),
+            out_shape=jax.ShapeDtypeStruct((l, mp, 1), jnp.int32),
+            compiler_params=tpu_compiler_params(
+                dimension_semantics=("parallel", "arbitrary"),
+            ),
+            name="plan_encode_place",
+            interpret=interpret,
+        )(pref_c, rank, hist)

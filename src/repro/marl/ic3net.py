@@ -18,6 +18,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import encoder
 from repro.core.flgw import FLGWConfig
 from repro.models.layers import dense_init, plan_of, proj
@@ -120,6 +121,11 @@ def policy_step(params, cfg: IC3NetConfig, obs, hc, gate_prev, plans=None):
     path); ``None``/``{}`` re-encodes inside each projection.
     Returns (action_logits (A,n_act), value (A,), gate_logits (A,2), new_hc).
     """
+    with jax.named_scope(scopes.POLICY):
+        return _policy_step(params, cfg, obs, hc, gate_prev, plans)
+
+
+def _policy_step(params, cfg: IC3NetConfig, obs, hc, gate_prev, plans):
     a = cfg.n_agents
     fl = cfg.flgw
     h, c = hc
@@ -128,21 +134,27 @@ def policy_step(params, cfg: IC3NetConfig, obs, hc, gate_prev, plans=None):
     # mean below is the one cross-agent reduction: on an agent-sharded
     # mesh it is the communication all-reduce, everything else is local.
     obs = constrain(obs, ("agent", None))
-    comm_src = jax.lax.stop_gradient(h) if cfg.comm_detach else h
-    cvec = proj(params["comm"], comm_src, fl,
-                plan=plan_of(plans, "comm"))             # (A, H)
-    cvec = cvec * gate_prev[:, None]
-    # gated mean over the *other* agents
-    total = jnp.sum(cvec, axis=0, keepdims=True)
-    denom = max(a - 1, 1)
-    comm_in = (total - cvec) / denom                      # (A, H)
-    e = jnp.tanh(proj(params["enc"], obs, fl, plan=plan_of(plans, "enc")))
+    with jax.named_scope(scopes.COMM):
+        comm_src = jax.lax.stop_gradient(h) if cfg.comm_detach else h
+        cvec = proj(params["comm"], comm_src, fl,
+                    plan=plan_of(plans, "comm"))         # (A, H)
+        cvec = cvec * gate_prev[:, None]
+        # gated mean over the *other* agents
+        total = jnp.sum(cvec, axis=0, keepdims=True)
+        denom = max(a - 1, 1)
+        comm_in = (total - cvec) / denom                  # (A, H)
+    with jax.named_scope(scopes.ENCODER):
+        e = jnp.tanh(proj(params["enc"], obs, fl,
+                          plan=plan_of(plans, "enc")))
     x = constrain(e + comm_in, ("agent", None))
-    h, c = lstm_cell(params, cfg, x, (h, c), plans)
+    with jax.named_scope(scopes.LSTM):
+        h, c = lstm_cell(params, cfg, x, (h, c), plans)
     h = constrain(h, ("agent", None))
-    logits = proj(params["policy"], h, fl, plan=plan_of(plans, "policy"))
-    value = proj(params["value"], h)[:, 0]
-    gate_logits = proj(params["gate"], h)
+    with jax.named_scope(scopes.HEADS):
+        logits = proj(params["policy"], h, fl,
+                      plan=plan_of(plans, "policy"))
+        value = proj(params["value"], h)[:, 0]
+        gate_logits = proj(params["gate"], h)
     return logits, value, gate_logits, (h, c)
 
 

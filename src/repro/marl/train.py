@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import kernels as kernels_mod
+from repro import scopes
 from repro.core import encoder, flgw, grouped
 from repro.core.schedule import SparsitySchedule
 from repro.launch.mesh import make_marl_mesh
@@ -99,34 +100,41 @@ def rollout(params, key, cfg: ic3net.IC3NetConfig, ecfg, env: envs_mod.Env,
     only exists when requested.
     """
     k_env, k_act = jax.random.split(key)
-    state = env.reset(k_env, ecfg)
+    with jax.named_scope(scopes.ENV):
+        state = env.reset(k_env, ecfg)
     hc, gate = ic3net.initial_state(cfg)
 
     def step_fn(carry, k):
         state, hc, gate, done = carry
-        obs = env.observe(state, ecfg)
+        with jax.named_scope(scopes.ENV):
+            obs = env.observe(state, ecfg)
         logits, value, gate_logits, hc = ic3net.policy_step(
             params, cfg, obs, hc, gate, plans)
-        action = jax.random.categorical(k, logits)              # (A,)
-        kg, _ = jax.random.split(k)
-        new_gate = jax.random.bernoulli(
-            kg, jax.nn.softmax(gate_logits)[:, 1]).astype(jnp.float32)
-        logp_a, entropy, gate_logp = _policy_terms(
-            logits, gate_logits, action, new_gate)
-        nstate, reward, ndone = env.step(state, action, ecfg)
-        # freeze transitions after done
-        reward = jnp.where(done, 0.0, reward)
-        nstate = jax.tree.map(
-            lambda a, b: jnp.where(done, a, b), state, nstate)
+        with jax.named_scope(scopes.SAMPLE):
+            action = jax.random.categorical(k, logits)          # (A,)
+            kg, _ = jax.random.split(k)
+            new_gate = jax.random.bernoulli(
+                kg, jax.nn.softmax(gate_logits)[:, 1]).astype(jnp.float32)
+            logp_a, entropy, gate_logp = _policy_terms(
+                logits, gate_logits, action, new_gate)
+        with jax.named_scope(scopes.ENV):
+            nstate, reward, ndone = env.step(state, action, ecfg)
+            # freeze transitions after done
+            reward = jnp.where(done, 0.0, reward)
+            nstate = jax.tree.map(
+                lambda a, b: jnp.where(done, a, b), state, nstate)
         out = (reward, logp_a, value, entropy, gate_logp, new_gate)
         if collect:
             out = out + (obs, action)
         return (nstate, hc, new_gate, done | ndone), out
 
     keys = jax.random.split(k_act, ecfg.max_steps)
-    (state, _, _, _), outs = jax.lax.scan(
-        step_fn, (state, hc, gate, jnp.zeros((), bool)), keys)
-    return outs + (env.success(state),)
+    with jax.named_scope(scopes.ROLLOUT):
+        (state, _, _, _), outs = jax.lax.scan(
+            step_fn, (state, hc, gate, jnp.zeros((), bool)), keys)
+    with jax.named_scope(scopes.ENV):
+        succ = env.success(state)
+    return outs + (succ,)
 
 
 def a2c_terms(rew, logp, val, ent, gate_logp, gates, succ,
@@ -144,19 +152,20 @@ def a2c_terms(rew, logp, val, ent, gate_logp, gates, succ,
     def disc(carry, r):
         carry = r + tcfg.gamma * carry
         return carry, carry
-    _, returns = jax.lax.scan(disc, jnp.zeros_like(rew[:, 0]),
-                              rew[:, ::-1].swapaxes(0, 1))
-    returns = returns[::-1].swapaxes(0, 1)                    # (B, T, A)
-    adv = returns - val
-    pg = -jnp.mean(logp * jax.lax.stop_gradient(adv))
-    vloss = jnp.mean(adv ** 2)
-    eloss = -jnp.mean(ent)
-    gloss = jnp.mean(gates)                                   # talk less
-    loss = pg + tcfg.value_coef * vloss + tcfg.entropy_coef * eloss \
-        + tcfg.gate_coef * gloss
-    return loss, {"success": jnp.mean(succ.astype(jnp.float32)),
-                  "return": jnp.mean(jnp.sum(rew, axis=1)),
-                  "loss": loss}
+    with jax.named_scope(scopes.A2C):
+        _, returns = jax.lax.scan(disc, jnp.zeros_like(rew[:, 0]),
+                                  rew[:, ::-1].swapaxes(0, 1))
+        returns = returns[::-1].swapaxes(0, 1)                # (B, T, A)
+        adv = returns - val
+        pg = -jnp.mean(logp * jax.lax.stop_gradient(adv))
+        vloss = jnp.mean(adv ** 2)
+        eloss = -jnp.mean(ent)
+        gloss = jnp.mean(gates)                               # talk less
+        loss = pg + tcfg.value_coef * vloss + tcfg.entropy_coef * eloss \
+            + tcfg.gate_coef * gloss
+        return loss, {"success": jnp.mean(succ.astype(jnp.float32)),
+                      "return": jnp.mean(jnp.sum(rew, axis=1)),
+                      "loss": loss}
 
 
 def a2c_loss(params, key, cfg, ecfg, tcfg: TrainConfig, env: envs_mod.Env,

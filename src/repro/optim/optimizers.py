@@ -17,6 +17,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 
 # ---------------------------------------------------------------------------
 # Utilities
@@ -55,7 +57,8 @@ def rmsprop(params, grads, state, *, lr: float = 1e-3, decay: float = 0.99,
         step = lr * g32 / (jnp.sqrt(s) + eps)
         return (p.astype(jnp.float32) - step).astype(p.dtype), s
 
-    out = jax.tree.map(upd, params, grads, state)
+    with jax.named_scope(scopes.RMSPROP):
+        out = jax.tree.map(upd, params, grads, state)
     new_params = jax.tree.map(lambda o: o[0], out,
                               is_leaf=lambda x: isinstance(x, tuple))
     new_state = jax.tree.map(lambda o: o[1], out,
