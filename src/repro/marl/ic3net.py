@@ -17,12 +17,20 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro import scopes
 from repro.core import encoder
 from repro.core.flgw import FLGWConfig
-from repro.models.layers import dense_init, plan_of, proj
+from repro.models.layers import dense_init, plan_of, proj, runs_kernel
 from repro.sharding.partition import constrain
+
+# What one policy step keeps for its backward (``policy_step``): the LSTM
+# input and gate pre-activations, and the encoder's pre-activation where a
+# kernel computes it. The rest is rebuilt from these and the step's inputs.
+LSTM_IN = "lstm_in"
+LSTM_GATES = "lstm_gates"
+ENC_PRE = "enc_pre"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +114,7 @@ def lstm_cell(params, cfg: IC3NetConfig, x, hc, plans=None):
     gates = proj(params["lstm_x"], x, fl, plan=plan_of(plans, "lstm_x")) \
         + proj(params["lstm_h"], h, fl, plan=plan_of(plans, "lstm_h")) \
         + params["lstm_b"]
+    gates = checkpoint_name(gates, LSTM_GATES)
     i, f, g, o = jnp.split(gates, 4, axis=-1)
     c = jax.nn.sigmoid(f + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
     h = jax.nn.sigmoid(o) * jnp.tanh(c)
@@ -120,9 +129,32 @@ def policy_step(params, cfg: IC3NetConfig, obs, hc, gate_prev, plans=None):
     ``plans``: cached sparse metadata from :func:`encode_plans` (grouped
     path); ``None``/``{}`` re-encodes inside each projection.
     Returns (action_logits (A,n_act), value (A,), gate_logits (A,2), new_hc).
+
+    Rematerialised: the backward keeps only the names of
+    :func:`saved_names` and the step's inputs, and recomputes the rest on
+    the vector units. A scan over steps then stacks 7 H-wide buffers per
+    agent and step (8 with a grouped encoder) besides the observation,
+    instead of 17. No LSTM, communication or head product runs twice, and
+    the forward is unchanged.
     """
+    step = jax.checkpoint(
+        lambda p, o, s, g, pl: _policy_step(p, cfg, o, s, g, pl),
+        prevent_cse=False,    # called inside lax.scan
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *saved_names(params, cfg)))
     with jax.named_scope(scopes.POLICY):
-        return _policy_step(params, cfg, obs, hc, gate_prev, plans)
+        return step(params, obs, hc, gate_prev, plans)
+
+
+def saved_names(params, cfg: IC3NetConfig) -> tuple[str, ...]:
+    """Residual names :func:`policy_step` keeps. The encoder's
+    pre-activation is kept only where a kernel computes it: rebuilding it
+    would run the kernel again, while a plain K=obs_dim product is cheaper
+    to redo than to store."""
+    names = (LSTM_IN, LSTM_GATES)
+    if runs_kernel(params["enc"], cfg.flgw):
+        names += (ENC_PRE,)
+    return names
 
 
 def _policy_step(params, cfg: IC3NetConfig, obs, hc, gate_prev, plans):
@@ -144,9 +176,12 @@ def _policy_step(params, cfg: IC3NetConfig, obs, hc, gate_prev, plans):
         denom = max(a - 1, 1)
         comm_in = (total - cvec) / denom                  # (A, H)
     with jax.named_scope(scopes.ENCODER):
-        e = jnp.tanh(proj(params["enc"], obs, fl,
-                          plan=plan_of(plans, "enc")))
-    x = constrain(e + comm_in, ("agent", None))
+        # Named before the tanh: the tanh's derivative reads its output
+        # before any name after it applies, so only its input can be kept.
+        e = jnp.tanh(checkpoint_name(
+            proj(params["enc"], obs, fl, plan=plan_of(plans, "enc")),
+            ENC_PRE))
+    x = checkpoint_name(constrain(e + comm_in, ("agent", None)), LSTM_IN)
     with jax.named_scope(scopes.LSTM):
         h, c = lstm_cell(params, cfg, x, (h, c), plans)
     h = constrain(h, ("agent", None))

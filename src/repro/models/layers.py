@@ -43,6 +43,13 @@ def dense_init(key, m: int, n: int, *, flgw: Optional[FLGWConfig] = None,
     return params, specs
 
 
+def runs_kernel(p: dict, flgw: Optional[FLGWConfig]) -> bool:
+    """Whether :func:`proj` computes this layer with the grouped kernel
+    (a custom-VJP Pallas call) rather than a plain product."""
+    return (flgw is not None and flgw.enabled and "ig" in p
+            and flgw.path == "grouped")
+
+
 def proj(p: dict, x: jax.Array, flgw: Optional[FLGWConfig] = None,
          *, transpose: bool = False,
          plan: Optional[GroupPlan] = None) -> jax.Array:
@@ -55,7 +62,7 @@ def proj(p: dict, x: jax.Array, flgw: Optional[FLGWConfig] = None,
     w = p["w"]
     if flgw is None or not flgw.enabled or "ig" not in p:
         return x @ (w.T if transpose else w)
-    if flgw.path == "grouped":
+    if runs_kernel(p, flgw):
         return grouped_apply(x, w, p["ig"], p["og"], flgw,
                              transpose=transpose, plan=plan)
     mask = mask_ste(p["ig"], p["og"], flgw.ste_temperature).astype(w.dtype)

@@ -11,17 +11,22 @@ process at a time may load the TPU library, and every test worker imports
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.core.grouped as grouped_mod
 from repro.core.flgw import FLGWConfig
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flgw_matmul import ops as fops
 from repro.kernels.plan_encode import ops as pe_ops
 from repro.kernels.tiling import compute_cap
+from repro.marl import envs, ic3net
+from repro.marl import train as mt
+from repro.optim.optimizers import rmsprop_init
 
 # IC3Net (paper config): hidden 128, 8 agents, env batch 32.
 IC3_HIDDEN, IC3_AGENTS, IC3_BATCH = 128, 8, 32
@@ -150,3 +155,61 @@ def test_flash_bwd_gemma2_heads(one_chip):
     text = _compile_text(jax.grad(loss, argnums=(0, 1, 2)),
                          *_flash_shapes(one_chip))
     assert text.count("tpu_custom_call") >= 3        # fwd, dq, dkv
+
+
+def _rollout_chunk_text(sharding, groups: int, monkeypatch) -> str:
+    """The MARL training chunk (two updates) at IC3Net's width, 3 agents,
+    20 steps, env batch 32, compiled at the highest precision."""
+    for mod in (grouped_mod, fops, pe_ops):
+        monkeypatch.setattr(mod, "default_interpret", lambda: False)
+    env = envs.get("predator_prey")
+    ecfg = env.config_cls(n_agents=3, size=5, vision=0, max_steps=20)
+    cfg = ic3net.IC3NetConfig(hidden=IC3_HIDDEN, n_agents=3,
+                              obs_dim=env.obs_dim(ecfg),
+                              n_actions=env.n_actions(ecfg),
+                              flgw_groups=groups,
+                              flgw_path="grouped" if groups > 1 else "dense")
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda k: ic3net.init(k, cfg)[0], key)
+    plans = jax.eval_shape(lambda p: ic3net.encode_plans(p, cfg), params)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        (params, jax.eval_shape(rmsprop_init, params),
+         jax.eval_shape(lambda: key), plans,
+         jax.ShapeDtypeStruct((), jnp.int32)))
+    # Whether a kernel interprets is fixed when it is traced: keep this
+    # trace out of, and away from, the caches the CPU tests use.
+    jax.clear_caches()
+    try:
+        with jax.default_matmul_precision("highest"):
+            return mt._train_chunk.lower(*args, 2, cfg, ecfg,
+                                         mt.TrainConfig(batch=IC3_BATCH), env,
+                                         None).compile().as_text()
+    finally:
+        jax.clear_caches()
+
+
+def _forward_rollout_widths(text: str) -> list:
+    """Trailing widths of the per-step buffers the forward step scan stacks
+    ([steps, batch, agents, width]) for its backward."""
+    for line in text.splitlines():
+        m = re.match(r"\s*%?\S+ = \((.*)\) while\(", line)
+        if m and 'jvp(vmap(rollout))/while"' in line \
+                and "transpose(" not in line:
+            return [int(d.split(",")[-1]) for d in
+                    re.findall(r"f32\[(20,%d,3,\d+)\]" % IC3_BATCH,
+                               m.group(1))]
+    raise AssertionError("no forward rollout scan in the chunk")
+
+
+@pytest.mark.parametrize("groups", [1, 4], ids=["dense", "grouped-g4"])
+def test_rollout_scan_stacks_few_residuals(one_chip, groups, monkeypatch):
+    """The rematerialised policy step: the forward scan stacks at most 8
+    H-wide buffers a step (a 4H buffer counts 4), and on the grouped path
+    no kernel runs again inside the recomputation."""
+    text = _rollout_chunk_text(one_chip, groups, monkeypatch)
+    widths = _forward_rollout_widths(text)
+    assert sum(w // IC3_HIDDEN for w in widths) <= 8, widths
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert bool(calls) == (groups > 1)
+    assert not [l for l in calls if "rematted_computation" in l]
