@@ -20,7 +20,7 @@ _SMOKE: Dict[str, Callable[[], ModelConfig]] = {}
 ARCH_IDS = (
     "gemma2_2b", "gemma2_27b", "gemma3_12b", "internlm2_20b",
     "paligemma_3b", "mixtral_8x22b", "arctic_480b", "whisper_large_v3",
-    "jamba_1_5_large", "mamba2_1_3b", "ic3net",
+    "jamba_1_5_large", "mamba2_1_3b", "deepseek_v2_lite", "ic3net",
 )
 
 # Shape cells (assignment): name -> (seq_len, global_batch, kind)

@@ -11,10 +11,13 @@ FlashAttention, extended with the features our architectures need:
   * causal + sliding-window masking by absolute position, with whole-block
     skipping (a fully-masked (bq, bk) tile never touches the MXU);
   * gemma-style attention-logit softcap (tanh), handled exactly in bwd;
-  * f32 accumulation, bf16/f32 operands.
+  * the operands' dtype on the MXU (bf16 products for bf16 inputs, the
+    probabilities and logit gradients cast to it) with f32 accumulation;
+  * a value head narrower than the query/key head (DeepSeek's MLA:
+    192-wide q/k, 128-wide v).
 
-Layouts: q (B, Hq, S, D), k/v (B, Hkv, T, D), out (B, Hq, S, D); the
-per-row statistics lse/delta are (B, Hq, S, 1).
+Layouts: q (B, Hq, S, D), k (B, Hkv, T, D), v (B, Hkv, T, Dv), out
+(B, Hq, S, Dv); the per-row statistics lse/delta are (B, Hq, S, 1).
 Backward is the standard two-pass scheme: a dq pass (grid over q blocks,
 stream k) and a dkv pass (grid over k blocks, stream q), both recomputing
 p from the saved logsumexp — nothing quadratic is ever stored.
@@ -79,8 +82,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(_block_live(iq, ik, bq, bk, causal=causal, window=window))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)            # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)            # (bk, D)
+        q = q_ref[0, 0]                                # (bq, D)
+        k = k_ref[0, 0]                                # (bk, D)
         z = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         z = _apply_softcap(z, softcap)
@@ -93,8 +96,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         p = jnp.exp(z - m_new[:, None])                # (bq, bk)
         l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
         m_ref[:, 0] = m_new
-        v = v_ref[0, 0].astype(jnp.float32)            # (bk, D)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+        v = v_ref[0, 0]                                # (bk, Dv)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
 
@@ -111,11 +115,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                               "bq", "bk", "interpret"))
 def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
               bq=512, bk=512, interpret=False):
-    """Returns (out, lse). Shapes: q (B,Hq,S,D), k/v (B,Hkv,T,D), lse
-    (B,Hq,S,1) — the trailing unit dim keeps the row statistics' block
-    ``(bq, 1)``, which Mosaic's (8, 128) tiling rule accepts."""
+    """Returns (out, lse). Shapes: q (B,Hq,S,D), k (B,Hkv,T,D), v
+    (B,Hkv,T,Dv), out (B,Hq,S,Dv), lse (B,Hq,S,1) — the trailing unit dim
+    keeps the row statistics' block ``(bq, 1)``, which Mosaic's (8, 128)
+    tiling rule accepts."""
     b, hq, s, d = q.shape
-    hkv, t = k.shape[1], k.shape[2]
+    hkv, t, dh_v = k.shape[1], k.shape[2], v.shape[3]
     qpk = hq // hkv
     scale = float(d ** -0.5) if scale is None else float(scale)
     bq = min(bq, s)
@@ -133,19 +138,19 @@ def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
                 pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bk, d),
                              lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-                pl.BlockSpec((1, 1, bk, d),
+                pl.BlockSpec((1, 1, bk, dh_v),
                              lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, dh_v), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
+                jax.ShapeDtypeStruct((b, hq, s, dh_v), q.dtype),
                 jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, dh_v), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
             ],
@@ -166,7 +171,7 @@ def flash_fwd(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
 def _recompute_p_dz(q, k, lse_blk, do, v, delta_blk, *, scale, softcap,
                     mask):
     """Shared bwd math for one (bq, bk) tile; ``lse_blk``/``delta_blk``
-    are (bq, 1) columns. Returns (p, dz)."""
+    are (bq, 1) columns. Returns (p, dz), both f32."""
     z_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
     z = _apply_softcap(z_raw, softcap)
@@ -192,15 +197,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(_block_live(iq, ik, bq, bk, causal=causal, window=window))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         mask = _block_mask(iq, ik, bq, bk, causal=causal, window=window)
         _, dz = _recompute_p_dz(q, k, lse_ref[0, 0], do, v, delta_ref[0, 0],
                                 scale=scale, softcap=softcap, mask=mask)
         acc_ref[...] += jax.lax.dot_general(
-            dz, k, (((1,), (0,)), ((), ())),
+            dz.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
     @pl.when(ik == nk - 1)
@@ -224,19 +226,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(_block_live(iq, ik, bq, bk, causal=causal, window=window))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         mask = _block_mask(iq, ik, bq, bk, causal=causal, window=window)
         p, dz = _recompute_p_dz(q, k, lse_ref[0, 0], do, v,
                                 delta_ref[0, 0], scale=scale,
                                 softcap=softcap, mask=mask)
         dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[...] += jax.lax.dot_general(
-            dz, q, (((0,), (0,)), ((), ())),
+            dz.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
     @pl.when((iq == nq - 1) & (hg == qpk - 1))
@@ -252,7 +251,7 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
               scale=None, bq=512, bk=512, interpret=False):
     """Returns (dq, dk, dv)."""
     b, hq, s, d = q.shape
-    hkv, t = k.shape[1], k.shape[2]
+    hkv, t, dh_v = k.shape[1], k.shape[2], v.shape[3]
     qpk = hq // hkv
     scale = float(d ** -0.5) if scale is None else float(scale)
     bq = min(bq, s)
@@ -277,9 +276,9 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
                 pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bk, d),
                              lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-                pl.BlockSpec((1, 1, bk, d),
+                pl.BlockSpec((1, 1, bk, dh_v),
                              lambda b, h, i, j, qpk=qpk: (b, h // qpk, j, 0)),
-                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, dh_v), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
             ],
@@ -305,9 +304,9 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
                              (b, g * qpk + hg, i, 0)),
                 pl.BlockSpec((1, 1, bk, d),
                              lambda b, g, j, hg, i: (b, g, j, 0)),
-                pl.BlockSpec((1, 1, bk, d),
+                pl.BlockSpec((1, 1, bk, dh_v),
                              lambda b, g, j, hg, i: (b, g, j, 0)),
-                pl.BlockSpec((1, 1, bq, d),
+                pl.BlockSpec((1, 1, bq, dh_v),
                              lambda b, g, j, hg, i, qpk=qpk:
                              (b, g * qpk + hg, i, 0)),
                 pl.BlockSpec((1, 1, bq, 1),
@@ -320,16 +319,16 @@ def flash_bwd(q, k, v, out, lse, do, *, causal=True, window=0, softcap=0.0,
             out_specs=[
                 pl.BlockSpec((1, 1, bk, d),
                              lambda b, g, j, hg, i: (b, g, j, 0)),
-                pl.BlockSpec((1, 1, bk, d),
+                pl.BlockSpec((1, 1, bk, dh_v),
                              lambda b, g, j, hg, i: (b, g, j, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((b, hkv, t, d), k.dtype),
-                jax.ShapeDtypeStruct((b, hkv, t, d), v.dtype),
+                jax.ShapeDtypeStruct((b, hkv, t, dh_v), v.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dh_v), jnp.float32),
             ],
             compiler_params=tpu_compiler_params(
                 dimension_semantics=("parallel", "parallel", "parallel",

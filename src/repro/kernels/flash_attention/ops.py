@@ -24,7 +24,8 @@ from repro.kernels.tiling import pick_block as _pick_block
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal=True, window=0, softcap=0.0,
                     scale=None, bq=512, bk=512, interpret=None):
-    """q: (B, Hq, S, D); k/v: (B, Hkv, T, D) -> (B, Hq, S, D)."""
+    """q: (B, Hq, S, D); k: (B, Hkv, T, D); v: (B, Hkv, T, Dv) ->
+    (B, Hq, S, Dv)."""
     out, _ = _fwd(q, k, v, causal, window, softcap, scale, bq, bk,
                   interpret)
     return out

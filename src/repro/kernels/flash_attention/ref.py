@@ -17,7 +17,8 @@ def ref_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, window: int = 0,
                   softcap: float = 0.0, scale: float | None = None
                   ) -> jax.Array:
-    """q: (B, Hq, S, D); k/v: (B, Hkv, T, D). Returns (B, Hq, S, D)."""
+    """q/k: (B, Hq|Hkv, S|T, D); v: (B, Hkv, T, Dv). Returns
+    (B, Hq, S, Dv)."""
     b, hq, s, d = q.shape
     hkv, t = k.shape[1], k.shape[2]
     qpk = hq // hkv
@@ -37,4 +38,4 @@ def ref_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     logits = jnp.where(allowed, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bgqst,bgtd->bgqsd", p, v.astype(jnp.float32))
-    return out.reshape(b, hq, s, d).astype(q.dtype)
+    return out.reshape(b, hq, s, v.shape[-1]).astype(q.dtype)

@@ -58,9 +58,10 @@ def _mask(q_pos, k_pos, *, causal: bool, window: int, prefix_len: int,
     return allowed
 
 
-def _attend(q, k, v, mask, cfg):
-    """q: (B, Sq, G, Q, D); k/v: (B, Sk, G, D); mask: (B, Sq, Sk) or (Sq, Sk)."""
-    scale = cfg.head_dim ** -0.5
+def _attend(q, k, v, mask, cfg, scale=None):
+    """q: (B, Sq, G, Q, D); k: (B, Sk, G, D); v: (B, Sk, G, Dv); mask:
+    (B, Sq, Sk) or (Sq, Sk). ``scale`` defaults to ``head_dim ** -0.5``."""
+    scale = cfg.head_dim ** -0.5 if scale is None else scale
     logits = jnp.einsum("bsgqd,btgd->bgqst", q, k,
                         preferred_element_type=jnp.float32) * scale
     if cfg.attn_softcap > 0:
@@ -71,6 +72,42 @@ def _attend(q, k, v, mask, cfg):
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgqst,btgd->bsgqd", probs, v)
     return out
+
+
+def ring_write(bufs: dict, new: dict, pos):
+    """Write ``new[name]`` (B, s, ...) into each decode ring buffer
+    ``bufs[name]`` (B, L, ...) at ``pos % L``. Returns (bufs, k_pos,
+    k_valid): the absolute position each ring slot holds after the write,
+    and whether it was ever written.
+
+    A scalar ``pos`` is the lockstep cache: every batch row shares one
+    stream offset. A (B,) ``pos`` is the per-slot cache
+    (``init_cache(per_slot=True)``): each row is an independent request
+    stream at its own offset, the continuous-batching contract, written
+    one token at a time; a slot reset to pos=0 invalidates its stale
+    entries for free (every unwritten ring index maps to a negative
+    absolute position).
+    """
+    t = next(iter(bufs.values())).shape[1]
+    s = next(iter(new.values())).shape[1]
+    idx = jnp.arange(t, dtype=jnp.int32)
+    write = pos % t
+    if jnp.ndim(pos) == 0:
+        out = {n: jax.lax.dynamic_update_slice_in_dim(bufs[n], new[n], write,
+                                                      axis=1) for n in bufs}
+        # Absolute position held by each ring slot after the write: the
+        # largest p ≤ pos with p ≡ idx (mod L); negative ⇒ never written.
+        k_pos = (pos - jnp.mod(pos - idx, t))[None]
+    else:
+        if s != 1:
+            raise ValueError(
+                "per-slot decode caches take single-token steps "
+                f"(got {s} tokens); multi-token prefill goes through "
+                "the cache-free path one token at a time")
+        rows = jnp.arange(pos.shape[0])
+        out = {n: bufs[n].at[rows, write].set(new[n][:, 0]) for n in bufs}
+        k_pos = pos[:, None] - jnp.mod(pos[:, None] - idx[None], t)
+    return out, k_pos, k_pos >= 0
 
 
 def _split_heads(x, n_kv, q_per_kv, hd):
@@ -122,38 +159,9 @@ def attention(p, x, positions, cfg, *, window: int = 0, causal: bool = True,
         # in the decode cells); multi-token prefill goes through the
         # cache-free path.
         pos = cache["pos"]
-        t = cache["k"].shape[1]
-        if jnp.ndim(pos) == 0:
-            # lockstep cache: every batch row shares one stream offset
-            write = pos % t
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, write,
-                                                     axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, write,
-                                                     axis=1)
-            idx = jnp.arange(t, dtype=jnp.int32)
-            # Absolute position held by each ring slot after the write: the
-            # largest p ≤ pos with p ≡ idx (mod L); negative ⇒ never written.
-            k_pos = (pos - jnp.mod(pos - idx, t))[None]
-            k_valid = (k_pos >= 0)
-        else:
-            # per-slot cache (``init_cache(per_slot=True)``): ``pos`` is
-            # (B,) — each batch row is an independent request stream at its
-            # own offset, the continuous-batching contract. Same ring-buffer
-            # semantics, applied row-wise; a slot reset to pos=0 invalidates
-            # its stale KV for free (every unwritten ring index maps to a
-            # negative absolute position below).
-            if s != 1:
-                raise ValueError(
-                    "per-slot decode caches take single-token steps "
-                    f"(got {s} tokens); multi-token prefill goes through "
-                    "the cache-free path one token at a time")
-            write = pos % t                                    # (B,)
-            rows = jnp.arange(b)
-            ck = cache["k"].at[rows, write].set(k[:, 0])
-            cv = cache["v"].at[rows, write].set(v[:, 0])
-            idx = jnp.arange(t, dtype=jnp.int32)
-            k_pos = pos[:, None] - jnp.mod(pos[:, None] - idx[None], t)
-            k_valid = k_pos >= 0                               # (B, L)
+        bufs, k_pos, k_valid = ring_write({"k": cache["k"], "v": cache["v"]},
+                                          {"k": k, "v": v}, pos)
+        ck, cv = bufs["k"], bufs["v"]
         new_cache = {"k": ck, "v": cv, "pos": pos + s}
         mask = _mask(positions, k_pos, causal=causal, window=window,
                      prefix_len=prefix_len, k_valid=k_valid)

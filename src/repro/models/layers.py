@@ -11,6 +11,7 @@ axis names consumed by ``repro.sharding.partition``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -74,15 +75,21 @@ def proj(p: dict, x: jax.Array, flgw: Optional[FLGWConfig] = None,
 # Norms / activations / embeddings
 # ---------------------------------------------------------------------------
 
-def rmsnorm_init(d: int):
-    return {"scale": jnp.zeros((d,), jnp.float32)}, {"scale": (None,)}
+def rmsnorm_init(d: int, plus_one: bool = True):
+    """The weight is ``1 + scale`` with scale from 0 (Gemma) when
+    ``plus_one``, else ``scale`` itself from 1 (Llama, DeepSeek)."""
+    scale = jnp.zeros((d,), jnp.float32) if plus_one else \
+        jnp.ones((d,), jnp.float32)
+    return {"scale": scale}, {"scale": (None,)}
 
 
-def rmsnorm(p: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rmsnorm(p: dict, x: jax.Array, eps: float = 1e-6,
+            plus_one: bool = True) -> jax.Array:
     dt = x.dtype
     x = x.astype(jnp.float32)
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (x * (1.0 + p["scale"])).astype(dt)
+    w = 1.0 + p["scale"] if plus_one else p["scale"]
+    return (x * w).astype(dt)
 
 
 def embed_init(key, vocab: int, d: int, dtype=jnp.bfloat16):
@@ -90,14 +97,24 @@ def embed_init(key, vocab: int, d: int, dtype=jnp.bfloat16):
     return {"embedding": e.astype(dtype)}, {"embedding": ("vocab", "embed")}
 
 
-def embed(p: dict, tokens: jax.Array, d_model: int) -> jax.Array:
+def head_init(key, vocab: int, d: int, dtype=jnp.bfloat16):
+    """An output head apart from the embedding, (vocab, d) like it."""
+    w = jax.random.normal(key, (vocab, d), jnp.float32) * d ** -0.5
+    return {"w": w.astype(dtype)}, {"w": ("vocab", "embed")}
+
+
+def embed(p: dict, tokens: jax.Array, d_model: int,
+          scale: bool = True) -> jax.Array:
     # Gemma-style sqrt(d) scaling keeps the residual stream O(1).
-    return p["embedding"][tokens] * jnp.asarray(
-        d_model ** 0.5, p["embedding"].dtype)
+    x = p["embedding"][tokens]
+    if scale:
+        x = x * jnp.asarray(d_model ** 0.5, p["embedding"].dtype)
+    return x
 
 
-def unembed(p: dict, x: jax.Array) -> jax.Array:
-    return x @ p["embedding"].T
+def unembed(w: jax.Array, x: jax.Array) -> jax.Array:
+    """Logits from the (vocab, d) output matrix."""
+    return x @ w.T
 
 
 def softcap(x: jax.Array, cap: float) -> jax.Array:
@@ -108,11 +125,15 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D); positions: (..., S) int32."""
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         freqs: Optional[jax.Array] = None) -> jax.Array:
+    """x: (..., S, H, D); positions: (..., S) int32. Rotates the pairs
+    (i, i + D/2) by ``positions * freqs[i]``; ``freqs`` defaults to
+    ``theta ** (-2i / D)``."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., S, half)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
@@ -150,13 +171,17 @@ def plan_of(plans, name: str) -> Optional[GroupPlan]:
     return plans.get(name)
 
 
+ACTIVATIONS = {"gelu_tanh": functools.partial(jax.nn.gelu, approximate=True),
+               "silu": jax.nn.silu}
+
+
 def mlp(p: dict, x: jax.Array, flgw: Optional[FLGWConfig] = None,
-        plans: Optional[dict] = None) -> jax.Array:
+        plans: Optional[dict] = None, act: str = "gelu_tanh") -> jax.Array:
+    fn = ACTIVATIONS[act]
     up = proj(p["up"], x, flgw, plan=plan_of(plans, "up"))
     if "gate" in p:
-        up = jax.nn.gelu(proj(p["gate"], x, flgw,
-                              plan=plan_of(plans, "gate"))) * up
+        up = fn(proj(p["gate"], x, flgw, plan=plan_of(plans, "gate"))) * up
     else:
-        up = jax.nn.gelu(up)
+        up = fn(up)
     up = constrain(up, ("batch", None, "ffn"))   # TP on the hidden dim
     return proj(p["down"], up, flgw, plan=plan_of(plans, "down"))

@@ -15,14 +15,17 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import encoder as planenc
 from repro.core.flgw import FLGWConfig
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.config import ModelConfig, SlotSpec
-from repro.models.layers import (embed, embed_init, mlp, mlp_init, plan_of,
-                                 rmsnorm, rmsnorm_init, softcap, unembed)
+from repro.models.layers import (embed, embed_init, head_init, mlp, mlp_init,
+                                 plan_of, rmsnorm, rmsnorm_init, softcap,
+                                 unembed)
 from repro.sharding.partition import constrain
 
 
@@ -50,23 +53,41 @@ def encode_plans(params, cfg: ModelConfig) -> planenc.PlanState:
 # Init
 # ---------------------------------------------------------------------------
 
+def _norm_init(cfg: ModelConfig):
+    return rmsnorm_init(cfg.d_model, cfg.norm_plus_one)
+
+
+def _norm(p, x, cfg: ModelConfig):
+    return rmsnorm(p, x, cfg.norm_eps, cfg.norm_plus_one)
+
+
+def head_weight(params, cfg: ModelConfig) -> jax.Array:
+    """The (vocab, d) output matrix: the embedding when tied."""
+    if cfg.tie_embeddings:
+        return params["embed"]["embedding"]
+    return params["head"]["w"]
+
+
 def _slot_init(key, cfg: ModelConfig, slot: SlotSpec):
     ks = jax.random.split(key, 8)
     p, s = {}, {}
-    p["norm1"], s["norm1"] = rmsnorm_init(cfg.d_model)
+    p["norm1"], s["norm1"] = _norm_init(cfg)
     if slot.mixer == "attn":
         p["mixer"], s["mixer"] = attn_mod.attn_init(
+            ks[0], cfg, flgw=_flgw_cfg(cfg, "attn"))
+    elif slot.mixer == "mla":
+        p["mixer"], s["mixer"] = mla_mod.mla_init(
             ks[0], cfg, flgw=_flgw_cfg(cfg, "attn"))
     else:
         p["mixer"], s["mixer"] = ssm_mod.ssm_init(
             ks[0], cfg, flgw=_flgw_cfg(cfg, "ssm"))
     if slot.cross:
-        p["norm_x"], s["norm_x"] = rmsnorm_init(cfg.d_model)
+        p["norm_x"], s["norm_x"] = _norm_init(cfg)
         p["cross"], s["cross"] = attn_mod.attn_init(
             ks[1], cfg, flgw=_flgw_cfg(cfg, "attn"))
     if slot.ffn == "none":
         return p, s
-    p["norm2"], s["norm2"] = rmsnorm_init(cfg.d_model)
+    p["norm2"], s["norm2"] = _norm_init(cfg)
     if slot.ffn == "mlp":
         p["ffn"], s["ffn"] = mlp_init(
             ks[2], cfg.d_model, cfg.d_ff, gated=cfg.gated_mlp,
@@ -114,12 +135,20 @@ def lm_init(key, cfg: ModelConfig):
         ks[0], cfg.vocab, cfg.d_model, cfg.dtype)
     params["blocks"], specs["blocks"] = _blocks_init(
         ks[1], cfg, cfg.pattern, cfg.n_blocks)
-    params["final_norm"], specs["final_norm"] = rmsnorm_init(cfg.d_model)
+    params["final_norm"], specs["final_norm"] = _norm_init(cfg)
     if cfg.encoder_layers:
         enc_slot = SlotSpec(mixer="attn", window=0, ffn="mlp", causal=False)
         params["encoder"], specs["encoder"] = _blocks_init(
             ks[2], cfg, (enc_slot,), cfg.encoder_layers)
-        params["enc_norm"], specs["enc_norm"] = rmsnorm_init(cfg.d_model)
+        params["enc_norm"], specs["enc_norm"] = _norm_init(cfg)
+    if cfg.lead or not cfg.tie_embeddings:
+        # keys of their own, so configurations without either keep theirs
+        kl, kh = jax.random.split(ks[3])
+        if cfg.lead:
+            params["lead"], specs["lead"] = _blocks_init(kl, cfg, cfg.lead, 1)
+        if not cfg.tie_embeddings:
+            params["head"], specs["head"] = head_init(
+                kh, cfg.vocab, cfg.d_model, cfg.dtype)
     return params, specs
 
 
@@ -136,10 +165,13 @@ def _slot_apply(p, x, positions, cfg: ModelConfig, slot: SlotSpec, *,
     attention/SSM mixer, the cross-attention, the MoE experts and the
     ``ffn`` projections all consume their own plan subtree, so no mixer
     ever falls back to per-call re-encoding when a PlanState is supplied.
+
+    Returns (x, aux, new_cache, stats): ``stats`` holds a dropless MoE
+    slot's counters (``moe.moe``), {} for other slots.
     """
     aux = jnp.zeros((), jnp.float32)
-    new_cache = {}
-    h = rmsnorm(p["norm1"], x, cfg.norm_eps)
+    new_cache, stats = {}, {}
+    h = _norm(p["norm1"], x, cfg)
     if slot.mixer == "attn":
         c = None
         if cache is not None:
@@ -152,6 +184,15 @@ def _slot_apply(p, x, positions, cfg: ModelConfig, slot: SlotSpec, *,
             plans=plan_of(plans, "mixer"))
         if nc is not None:
             new_cache.update({"k": nc["k"], "v": nc["v"]})
+    elif slot.mixer == "mla":
+        c = None if cache is None else dict(cache, pos=pos)
+        with jax.named_scope(scopes.MLA):
+            h, nc = mla_mod.mla(p["mixer"], h, positions, cfg, cache=c,
+                                flash=cfg.use_flash,
+                                flgw=_flgw_cfg(cfg, "attn"),
+                                plans=plan_of(plans, "mixer"))
+        if nc is not None:
+            new_cache.update({"c": nc["c"], "k_pe": nc["k_pe"]})
     else:
         h, nc = ssm_mod.ssm(p["mixer"], h, cfg, cache=cache and
                             {"state": cache["state"], "conv": cache["conv"]},
@@ -162,27 +203,31 @@ def _slot_apply(p, x, positions, cfg: ModelConfig, slot: SlotSpec, *,
             new_cache.update(nc)
     x = x + h
     if slot.cross:
-        h = rmsnorm(p["norm_x"], x, cfg.norm_eps)
+        h = _norm(p["norm_x"], x, cfg)
         h, _ = attn_mod.attention(
             p["cross"], h, positions, cfg, causal=False, kv_x=encoder_out,
             q_chunk=q_chunk, flgw=_flgw_cfg(cfg, "attn"),
             plans=plan_of(plans, "cross"))
         x = x + h
     if slot.ffn == "none":     # pure-SSM blocks (mamba2) have no FFN
-        return x, aux, new_cache
-    h = rmsnorm(p["norm2"], x, cfg.norm_eps)
+        return x, aux, new_cache, stats
+    h = _norm(p["norm2"], x, cfg)
     if slot.ffn == "mlp":
-        h = mlp(p["ffn"], h, _flgw_cfg(cfg, "mlp"),
-                plans=plan_of(plans, "ffn"))
+        with jax.named_scope(scopes.DENSE_MLP):
+            h = mlp(p["ffn"], h, _flgw_cfg(cfg, "mlp"),
+                    plans=plan_of(plans, "ffn"), act=cfg.mlp_act)
     else:
-        h, a = moe_mod.moe(p["moe"], h, cfg, flgw=_flgw_cfg(cfg, "moe"),
-                           dropless=moe_dropless or cache is not None,
-                           plans=plan_of(plans, "moe"))
+        h, a, stats = moe_mod.moe(p["moe"], h, cfg,
+                                  flgw=_flgw_cfg(cfg, "moe"),
+                                  dropless=moe_dropless or cache is not None,
+                                  plans=plan_of(plans, "moe"))
         aux = aux + a
         if slot.ffn == "moe_dense":
-            h = h + mlp(p["ffn"], rmsnorm(p["norm2"], x, cfg.norm_eps),
-                        _flgw_cfg(cfg, "mlp"), plans=plan_of(plans, "ffn"))
-    return x + h, aux, new_cache
+            with jax.named_scope(scopes.DENSE_MLP):
+                h = h + mlp(p["ffn"], _norm(p["norm2"], x, cfg),
+                            _flgw_cfg(cfg, "mlp"),
+                            plans=plan_of(plans, "ffn"), act=cfg.mlp_act)
+    return x + h, aux, new_cache, stats
 
 
 def _apply_blocks(params, cfg: ModelConfig, pattern, x, positions, *,
@@ -190,6 +235,8 @@ def _apply_blocks(params, cfg: ModelConfig, pattern, x, positions, *,
                   q_chunk=512, banded=False, remat=False, ssd_unroll=False,
                   unroll_blocks=False, moe_dropless=False,
                   attn_identity=False, plans=None):
+    """Returns (x, aux, new_caches, stats): ``stats`` maps each MoE slot
+    with counters to its (n_blocks,)-stacked counters."""
     has_cache = caches is not None
     plans = plans or {}   # nested dict: slot{i} -> ffn -> stacked GroupPlans
 
@@ -200,10 +247,10 @@ def _apply_blocks(params, cfg: ModelConfig, pattern, x, positions, *,
             block_p, block_c, block_pl = xs
         else:
             (block_p, block_pl), block_c = xs, None
-        new_c = {}
+        new_c, stats = {}, {}
         for i, slot in enumerate(pattern):
             c_i = None if block_c is None else block_c.get(f"slot{i}")
-            x, a, nc = _slot_apply(
+            x, a, nc, st = _slot_apply(
                 block_p[f"slot{i}"], x, positions, cfg, slot, cache=c_i,
                 pos=pos, encoder_out=encoder_out, prefix_len=prefix_len,
                 q_chunk=q_chunk, banded=banded, ssd_unroll=ssd_unroll,
@@ -212,7 +259,9 @@ def _apply_blocks(params, cfg: ModelConfig, pattern, x, positions, *,
             aux = aux + a
             if nc:
                 new_c[f"slot{i}"] = nc
-        return (x, aux), (new_c if new_c else None)
+            if st:
+                stats[f"slot{i}"] = st
+        return (x, aux), (new_c if new_c else None, stats)
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
@@ -232,12 +281,23 @@ def _apply_blocks(params, cfg: ModelConfig, pattern, x, positions, *,
             carry, o = body(carry, xs_i)
             outs.append(o)
         (x, aux) = carry
-        new_caches = (None if outs[0] is None
-                      else jax.tree.map(lambda *ls: jnp.stack(ls), *outs))
-        return x, aux, new_caches
+        new_caches, stats = jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
+        return x, aux, new_caches, stats
 
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0), xs)
-    return x, aux, new_caches
+    (x, aux), (new_caches, stats) = jax.lax.scan(body, (x, aux0), xs)
+    return x, aux, new_caches, stats
+
+
+def _layer_stats(stacks, key: str) -> jax.Array:
+    """One counter of every MoE layer, in layer order: ``stacks`` are the
+    (stats, period) of the lead and the scanned blocks."""
+    parts = []
+    for stats, period in stacks:
+        names = [f"slot{i}" for i in range(period) if f"slot{i}" in stats]
+        if names:
+            parts.append(jnp.stack([stats[n][key] for n in names],
+                                   axis=1).reshape(-1))
+    return jnp.concatenate(parts)
 
 
 def lm_apply(params, cfg: ModelConfig, tokens, positions, *,
@@ -245,7 +305,7 @@ def lm_apply(params, cfg: ModelConfig, tokens, positions, *,
              banded=False, remat=None, return_hidden=False,
              ssd_unroll=False, unroll_blocks=False, moe_dropless=False,
              attn_identity=False, plans=None):
-    """Forward pass. Returns (logits, aux_loss, new_cache).
+    """Forward pass. Returns (logits, aux, new_cache).
 
     tokens: (B, S) int32; positions: (B, S) int32.
     patch_embeds: (B, prefix, d) VLM stub prefix (prefill only).
@@ -259,6 +319,11 @@ def lm_apply(params, cfg: ModelConfig, tokens, positions, *,
     path falls back to per-projection re-encoding.
     return_hidden: skip unembedding — the training loss computes logits in
     sequence chunks (the full (B, S, vocab) tensor at 256k vocab never fits).
+
+    ``aux`` holds ``balance``, the MoE balance loss summed over layers, and
+    for dropless MoE layers two counters a layer (``moe.moe``): ``routed``,
+    the assignments to held experts, and ``peak``, the largest held
+    expert's load over their mean.
     """
     remat = cfg.remat if remat is None else remat
     if plans is None and cache is not None:
@@ -266,7 +331,8 @@ def lm_apply(params, cfg: ModelConfig, tokens, positions, *,
     if isinstance(plans, planenc.PlanState):
         plans = plans.plans
     plans = plans or {}
-    x = embed(params["embed"], tokens, cfg.d_model).astype(cfg.dtype)
+    x = embed(params["embed"], tokens, cfg.d_model,
+              cfg.embed_scale).astype(cfg.dtype)
     prefix_len = 0
     if patch_embeds is not None:
         x = jnp.concatenate([patch_embeds.astype(cfg.dtype), x], axis=1)
@@ -281,36 +347,51 @@ def lm_apply(params, cfg: ModelConfig, tokens, positions, *,
             enc_pos = jnp.broadcast_to(
                 jnp.arange(frames.shape[1], dtype=jnp.int32)[None],
                 frames.shape[:2])
-            eo, _, _ = _apply_blocks(
+            eo, _, _, _ = _apply_blocks(
                 params["encoder"], cfg, (enc_slot,),
                 frames.astype(cfg.dtype), enc_pos, q_chunk=q_chunk,
                 remat=remat, ssd_unroll=ssd_unroll,
                 unroll_blocks=unroll_blocks, plans=plans.get("encoder"))
-            encoder_out = rmsnorm(params["enc_norm"], eo, cfg.norm_eps)
+            encoder_out = _norm(params["enc_norm"], eo, cfg)
             # Encoder self-attn must be bidirectional: handled by window=0 &
             # causal mask relaxation below (prefix over the whole stream).
         elif cache is not None:
             encoder_out = cache["encoder_out"]
 
     pos = None if cache is None else cache["pos"]
+    common = dict(pos=pos, encoder_out=encoder_out, prefix_len=prefix_len,
+                  q_chunk=q_chunk, banded=banded,
+                  remat=remat and cache is None, ssd_unroll=ssd_unroll,
+                  unroll_blocks=unroll_blocks, moe_dropless=moe_dropless,
+                  attn_identity=attn_identity)
+    aux, new_lead, stacks = jnp.zeros((), jnp.float32), None, []
+    if cfg.lead:
+        x, aux, new_lead, st = _apply_blocks(
+            params["lead"], cfg, cfg.lead, x, positions,
+            caches=None if cache is None else cache["lead"],
+            plans=plans.get("lead"), **common)
+        stacks.append((st, len(cfg.lead)))
     slot_caches = None if cache is None else cache["blocks"]
-    x, aux, new_slot_caches = _apply_blocks(
+    x, a, new_slot_caches, st = _apply_blocks(
         params["blocks"], cfg, cfg.pattern, x, positions, caches=slot_caches,
-        pos=pos, encoder_out=encoder_out, prefix_len=prefix_len,
-        q_chunk=q_chunk, banded=banded, remat=remat and cache is None,
-        ssd_unroll=ssd_unroll, unroll_blocks=unroll_blocks,
-        moe_dropless=moe_dropless, attn_identity=attn_identity,
-        plans=plans.get("blocks"))
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        plans=plans.get("blocks"), **common)
+    stacks.append((st, cfg.period))
+    aux = {"balance": aux + a}
+    if any(st for st, _ in stacks):
+        aux.update(routed=_layer_stats(stacks, "routed"),
+                   peak=_layer_stats(stacks, "peak"))
+    x = _norm(params["final_norm"], x, cfg)
     if return_hidden:
         out = x if prefix_len == 0 else x[:, prefix_len:]
     else:
-        logits = unembed(params["embed"], x)
+        logits = unembed(head_weight(params, cfg), x)
         out = softcap(logits.astype(jnp.float32), cfg.logit_softcap)
 
     new_cache = None
     if cache is not None:
         new_cache = {"pos": pos + tokens.shape[1], "blocks": new_slot_caches}
+        if cfg.lead:
+            new_cache["lead"] = new_lead
         if "plans" in cache:
             # plans ride the cache unchanged — params are frozen *within*
             # a request; across requests (online tuning) the serving loop
@@ -364,24 +445,37 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     request. The lockstep scalar layout stays the default.
     """
     dtype = dtype or cfg.dtype
-    nb = cfg.n_blocks
-    blocks = {}
-    for i, slot in enumerate(cfg.pattern):
-        if slot.mixer == "attn":
-            kv = (nb, batch, _cache_len(slot, max_seq), cfg.n_kv_heads,
-                  cfg.head_dim)
-            blocks[f"slot{i}"] = {
-                "k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
-        else:
-            conv_ch = cfg.d_inner + 2 * cfg.ssm_state
-            blocks[f"slot{i}"] = {
-                "state": jnp.zeros((nb, batch, cfg.ssm_heads,
-                                    cfg.ssm_head_dim, cfg.ssm_state),
-                                   jnp.float32),
-                "conv": jnp.zeros((nb, batch, cfg.conv_width - 1, conv_ch),
-                                  dtype)}
+
+    def slots(pattern, nb):
+        blocks = {}
+        for i, slot in enumerate(pattern):
+            if slot.mixer == "attn":
+                kv = (nb, batch, _cache_len(slot, max_seq), cfg.n_kv_heads,
+                      cfg.head_dim)
+                blocks[f"slot{i}"] = {
+                    "k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+            elif slot.mixer == "mla":
+                # the normalised latent and the rotated key, per token
+                blocks[f"slot{i}"] = {
+                    "c": jnp.zeros((nb, batch, max_seq, cfg.kv_lora_rank),
+                                   dtype),
+                    "k_pe": jnp.zeros((nb, batch, max_seq, cfg.qk_rope_dim),
+                                      dtype)}
+            else:
+                conv_ch = cfg.d_inner + 2 * cfg.ssm_state
+                blocks[f"slot{i}"] = {
+                    "state": jnp.zeros((nb, batch, cfg.ssm_heads,
+                                        cfg.ssm_head_dim, cfg.ssm_state),
+                                       jnp.float32),
+                    "conv": jnp.zeros((nb, batch, cfg.conv_width - 1,
+                                       conv_ch), dtype)}
+        return blocks
+
+    blocks = slots(cfg.pattern, cfg.n_blocks)
     pos_shape = (batch,) if per_slot else ()
     cache = {"pos": jnp.zeros(pos_shape, jnp.int32), "blocks": blocks}
+    if cfg.lead:
+        cache["lead"] = slots(cfg.lead, 1)
     plans = ()
     if params is not None:
         state = encode_plans(params, cfg)
@@ -478,17 +572,25 @@ def cache_specs(cfg: ModelConfig, *, per_slot: bool = False) -> dict:
     This is the flash-decoding-style layout: each model shard scores its
     slice of the KV cache and the tiny (B, H, hd) partial results reduce.
     """
-    blocks = {}
-    for i, slot in enumerate(cfg.pattern):
-        if slot.mixer == "attn":
-            kv = ("layers", "batch", "seq_kv", "kv_heads", None)
-            blocks[f"slot{i}"] = {"k": kv, "v": kv}
-        else:
-            blocks[f"slot{i}"] = {
-                "state": ("layers", "batch", "heads", None, None),
-                "conv": ("layers", "batch", None, "ffn")}
-    specs = {"pos": ("batch",) if per_slot else (), "blocks": blocks,
+    def slots(pattern):
+        blocks = {}
+        for i, slot in enumerate(pattern):
+            if slot.mixer == "attn":
+                kv = ("layers", "batch", "seq_kv", "kv_heads", None)
+                blocks[f"slot{i}"] = {"k": kv, "v": kv}
+            elif slot.mixer == "mla":
+                lat = ("layers", "batch", "seq_kv", None)
+                blocks[f"slot{i}"] = {"c": lat, "k_pe": lat}
+            else:
+                blocks[f"slot{i}"] = {
+                    "state": ("layers", "batch", "heads", None, None),
+                    "conv": ("layers", "batch", None, "ffn")}
+        return blocks
+    specs = {"pos": ("batch",) if per_slot else (),
+             "blocks": slots(cfg.pattern),
              "plans": plan_specs(cfg, compact=True)}
+    if cfg.lead:
+        specs["lead"] = slots(cfg.lead)
     if cfg.encoder_layers:
         specs["encoder_out"] = ("batch", None, None)
     return specs

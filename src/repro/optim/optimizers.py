@@ -100,7 +100,8 @@ def adamw(params, grads, state: AdamWState, *, lr: float = 3e-4,
         p32 = p32 - step - lr * weight_decay * p32
         return p32.astype(p.dtype), m, v
 
-    out = jax.tree.map(upd, params, grads, state.mu, state.nu)
+    with jax.named_scope(scopes.ADAMW):
+        out = jax.tree.map(upd, params, grads, state.mu, state.nu)
     pick = lambda i: jax.tree.map(lambda o: o[i], out,
                                   is_leaf=lambda x: isinstance(x, tuple))
     return pick(0), AdamWState(mu=pick(1), nu=pick(2), count=count)

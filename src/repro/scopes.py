@@ -1,4 +1,4 @@
-"""Names of the layers of the MARL training step.
+"""Names of the layers of the MARL and LM training steps.
 
 Each name is a ``jax.named_scope`` placed where the work happens, so every
 HLO instruction of the compiled step carries the layer it belongs to in
@@ -22,3 +22,16 @@ PLAN_REFRESH = "plan_refresh"  # FLGW plan re-encode (grouped path only)
 
 LAYER_SCOPES = (ROLLOUT, ENV, POLICY, COMM, ENCODER, LSTM, HEADS, SAMPLE,
                 A2C, RMSPROP, PLAN_REFRESH)
+
+# The layers of the LM training step (``train.step``), read by the LM
+# cell's own reader; ``LAYER_SCOPES`` above is the MARL step's.
+MLA = "mla"                    # latent attention, the flash core inside it
+MOE_ROUTE = "moe_route"        # router, top-k, balance loss, dispatch, combine
+EXPERTS = "experts"            # the held routed experts' products
+SHARED_EXPERTS = "shared_experts"  # the shared experts' SwiGLU
+DENSE_MLP = "dense_mlp"        # a dense layer's MLP
+LM_HEAD = "lm_head"            # output head and chunked cross-entropy
+ADAMW = "adamw"                # the optimizer update
+
+LM_SCOPES = (MLA, MOE_ROUTE, EXPERTS, SHARED_EXPERTS, DENSE_MLP, LM_HEAD,
+             ADAMW)

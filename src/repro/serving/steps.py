@@ -114,7 +114,7 @@ def make_prefill_step(cfg, *, plan_policy: str = "certify",
             moe_dropless=True, attn_identity=attn_identity, plans=plans)
         # Only the last position's logits are needed to start decoding.
         from repro.models.layers import softcap, unembed
-        logits = unembed(params["embed"], hidden[:, -1:])
+        logits = unembed(transformer.head_weight(params, cfg), hidden[:, -1:])
         return softcap(logits.astype(jnp.float32), cfg.logit_softcap)
 
     return prefill_step

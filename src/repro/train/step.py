@@ -23,6 +23,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.core import encoder as planenc
 from repro.core.flgw import FLGWConfig
 from repro.models import transformer
@@ -56,10 +57,14 @@ def _loss_fn(params, batch, cfg: ModelConfig, q_chunk: int, banded: bool,
         q_chunk=q_chunk, banded=banded, return_hidden=True,
         ssd_unroll=ssd_unroll, unroll_blocks=unroll_blocks,
         attn_identity=attn_identity, plans=plans)
-    ce = chunked_cross_entropy(
-        hidden, params["embed"]["embedding"], batch["targets"],
-        logit_softcap=cfg.logit_softcap, chunk=ce_chunk)
-    return ce + 0.01 * aux, {"ce": ce, "aux": aux}
+    with jax.named_scope(scopes.LM_HEAD):
+        ce = chunked_cross_entropy(
+            hidden, transformer.head_weight(params, cfg), batch["targets"],
+            logit_softcap=cfg.logit_softcap, chunk=ce_chunk)
+    metrics = {"ce": ce, "aux": aux["balance"]}
+    if "routed" in aux:
+        metrics.update(moe_routed=aux["routed"], moe_peak=aux["peak"])
+    return ce + cfg.aux_weight * aux["balance"], metrics
 
 
 def make_train_step(cfg: ModelConfig, *, optimizer: str = "adamw",

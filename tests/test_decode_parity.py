@@ -51,6 +51,13 @@ def test_parity_global_attention():
     _full_then_decode(cfg, seq=12)
 
 
+def test_parity_latent_attention_and_held_experts():
+    """DeepSeek-V2-Lite's smoke: MLA decodes from its cached latent and
+    rotated key, through the leading dense layer and the dropless MoE."""
+    cfg = registry.get_smoke_config("deepseek_v2_lite")
+    _full_then_decode(cfg, seq=12)
+
+
 def test_parity_sliding_window_ring_buffer_wraps():
     """seq > window: the ring buffer must overwrite old positions and the
     decode output must still match the windowed full forward."""

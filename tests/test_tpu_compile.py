@@ -10,6 +10,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 every test file.
 """
+import base64
 import os
 import re
 
@@ -213,3 +214,44 @@ def test_rollout_scan_stacks_few_residuals(one_chip, groups, monkeypatch):
     calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert bool(calls) == (groups > 1)
     assert not [l for l in calls if "rematted_computation" in l]
+
+
+def test_deepseek_v2_lite_cut_train_step(one_chip, monkeypatch):
+    """The benchmark's DeepSeek-V2-Lite cut at its published widths (6
+    layers, 8 of 64 experts held, 12,800-id vocabulary), 2 sequences of
+    4096: the whole train step, with MLA's flash kernels at a 192-wide
+    query/key head beside a 128-wide value head and the held experts'
+    dropless grouped products (two chunks of the dispatch)."""
+    import json
+    from bench.runners import lm
+    from repro.train import state as state_lib
+    from repro.train import step as step_lib
+    monkeypatch.setattr(flash_ops, "default_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "deepseek-v2-lite-e8.json")) as f:
+        cfg = lm.program_config(json.load(f))
+    spec = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip), t)
+    state = spec(state_lib.abstract_state(cfg))
+    batch = {k: jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
+             for k in ("tokens", "targets", "positions")}
+    jax.clear_caches()
+    try:
+        text = jax.jit(step_lib.make_train_step(cfg), donate_argnums=0).lower(
+            state, batch).compile().as_text()
+    finally:
+        jax.clear_caches()
+    kernels = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    flash = [l for l in kernels if 'op_name="' in l and "/flash_" in l]
+    assert any("bf16[2,16,4096,128]" in l for l in flash)     # value head
+    assert any("bf16[2,16,4096,192]" in l for l in flash)     # q/k head
+    # the held experts' products (forward, and both gradients): each
+    # kernel's grid over rows is sized at run time, by the tiles that the
+    # held assignments fill, so their work follows the routed count
+    products = [l for l in kernels if re.search(r"%ragged-dot-(?!metadata)", l)]
+    assert len(products) >= 3
+    for line in products:
+        body = base64.b64decode(re.search(r'"body":"([^"]+)"', line)[1])
+        bounds = re.search(rb"iteration_bounds = array<i64: ([^>]*)>", body)[1]
+        assert b"-9223372036854775808" in bounds, bounds     # dynamic
